@@ -19,9 +19,12 @@ its pre-engine baseline on the paper CNN:
 
 A fourth claim rides along since the sharded engine (docs/SHARDING.md):
 the per-shard fused aggregate→quantize path produces **bit-identical**
-int8 codes on 1 device and on an 8-way forced host-platform mesh — the
+int8 codes on 1 device and sharded over every device present — the
 ``sharded`` rows carry a codes checksum from each device count so the
-artifact records the equivalence, not just the timing.
+artifact records the equivalence, not just the timing. Both rows run in
+this process (a child would need the chip this process holds); run
+under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to get a
+multi-device row on the CPU.
 
 A fifth rides along since secure aggregation (docs/SECUREAGG.md): the
 ``sharded`` rows also time the fused unmask→aggregate→quantize path
@@ -37,10 +40,7 @@ from __future__ import annotations
 import argparse
 import hashlib
 import json
-import os
 import platform
-import subprocess
-import sys
 import time
 
 import jax
@@ -168,13 +168,9 @@ def bench_cohort(S: int, reps: int, *, image=(32, 32, 3), samples=40,
     }
 
 
-def _sharded_row(reps: int) -> dict:
-    """One sharded-aggregation row at the *current* device count.
-
-    Runs in the ``--_sharded-worker`` subprocess: the parent sets
-    ``xla_force_host_platform_device_count`` in the env before this
-    interpreter imports jax (the count is locked at first init).
-    """
+def _sharded_row(reps: int, sharded: bool) -> dict:
+    """One aggregation row on one device, or sharded over every device
+    present (``make_engine_mesh``; None on a single device)."""
     from repro.kernels.fused import shard_align
     from repro.launch.mesh import make_engine_mesh
 
@@ -184,7 +180,7 @@ def _sharded_row(reps: int) -> dict:
     fms = [FlatModel.pack(jax.tree.map(lambda l: l + i * 0.01, params), spec)
            for i in range(5)]
     w = [1.0] * 5
-    mesh = make_engine_mesh()
+    mesh = make_engine_mesh() if sharded else None
     shardings = spec.sharding(mesh) if mesh is not None else None
     shards = shardings.n_shards if shardings is not None else 1
     local_n = shard_align(spec.n, shards) // shards if shards > 1 else spec.n
@@ -219,7 +215,7 @@ def _sharded_row(reps: int) -> dict:
         shardings=shardings)[1], reps)
 
     return {
-        "model": "paper-cnn", "P": 5, "devices": jax.device_count(),
+        "model": "paper-cnn", "P": 5, "devices": shards,
         "model_shards": shards,
         "padded_n": shard_align(spec.n, shards) if shards > 1 else spec.n,
         "local_tile": tile_for(local_n, 5),
@@ -235,31 +231,13 @@ def _sharded_row(reps: int) -> dict:
 
 
 def bench_sharded(reps: int) -> list[dict]:
-    """1-vs-8-device sharded aggregation rows (docs/SHARDING.md).
-
-    jax locks the device count at first init, so each row runs in its own
-    subprocess whose env forces the host-platform device count before the
-    interpreter imports jax. On this CPU container the 8 forced devices
-    share one threadpool, so the rows validate the sharded path's
-    *bit-identity* (matching ``codes_sha256``), not a speedup — the
-    per-shard VMEM tiles pay off on real multi-chip meshes.
-    """
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    rows = []
-    for n_dev in (1, 8):
-        env = dict(os.environ)
-        flags = [f for f in env.get("XLA_FLAGS", "").split()
-                 if "xla_force_host_platform_device_count" not in f]
-        env["XLA_FLAGS"] = " ".join(
-            flags + [f"--xla_force_host_platform_device_count={n_dev}"])
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(root, "src")] +
-            ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_kernels",
-             "--_sharded-worker", "--reps", str(reps)],
-            capture_output=True, text=True, env=env, cwd=root, check=True)
-        rows.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    """1-device vs all-devices sharded aggregation rows (docs/SHARDING.md),
+    both in this process. Forced host devices share one CPU threadpool,
+    so there the rows validate the sharded path's *bit-identity*
+    (matching ``codes_sha256``), not a speedup."""
+    rows = [_sharded_row(reps, sharded=False)]
+    if jax.device_count() > 1:
+        rows.append(_sharded_row(reps, sharded=True))
     return rows
 
 
@@ -321,11 +299,5 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized subset (same JSON artifact)")
-    ap.add_argument("--_sharded-worker", action="store_true",
-                    help=argparse.SUPPRESS)   # bench_sharded subprocess
-    ap.add_argument("--reps", type=int, default=5, help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if getattr(args, "_sharded_worker"):
-        print(json.dumps(_sharded_row(args.reps)))
-    else:
-        run(quick=args.quick)
+    run(quick=args.quick)

@@ -8,6 +8,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
 
 

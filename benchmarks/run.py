@@ -37,6 +37,7 @@ def main() -> None:
     only = {s.strip() for s in args.only.split(",") if s.strip()}
 
     summary = []
+    failed = []
     for name, module in BENCHES:
         if only and not any(o in name for o in only):
             continue
@@ -48,6 +49,7 @@ def main() -> None:
             status = f"rows={len(rows) if rows else 0}"
         except Exception as e:  # pragma: no cover
             status = f"ERROR {e!r}"
+            failed.append(name)
             print(f"[bench] {name} failed: {e!r}", file=sys.stderr)
         dt = time.time() - t0
         summary.append({"name": name,
@@ -56,6 +58,8 @@ def main() -> None:
 
     print("\n=== summary (name,us_per_call,derived) ===")
     emit(summary, "summary.csv")
+    if failed:
+        sys.exit(f"[bench] failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -31,7 +31,6 @@ from repro.config import MeshConfig, ModelConfig, ShapeConfig, TrainConfig
 from repro.core.strategy import Strategy, build_strategy
 from repro.models import Model, build
 from repro.sharding import ShardingPolicy, input_specs
-from repro.utils.compat import jit_shardings
 
 
 class TrainState(NamedTuple):
@@ -187,9 +186,8 @@ class DistributedTrainer:
         step = self.build_train_step(**kw)
         return jax.jit(
             step,
-            in_shardings=jit_shardings(
-                self.mesh, (specs, batch_spec, self.policy.weights_spec())),
-            out_shardings=jit_shardings(self.mesh, (specs, None)),
+            in_shardings=(specs, batch_spec, self.policy.weights_spec()),
+            out_shardings=(specs, None),
             donate_argnums=(0,) if self._donate else ())
 
 
@@ -239,9 +237,8 @@ class Server:
         bspec = self.policy.batch_spec(batch_t, with_participants=False,
                                        shard_seq=self.shard_seq)
         return jax.jit(self.model.prefill,
-                       in_shardings=jit_shardings(self.mesh,
-                                                  (pspec, bspec, cspec)),
-                       out_shardings=jit_shardings(self.mesh, (None, cspec)))
+                       in_shardings=(pspec, bspec, cspec),
+                       out_shardings=(None, cspec))
 
     def jit_decode(self, params_t, cache_t, batch_size: Optional[int] = None):
         from jax.sharding import PartitionSpec as P
@@ -252,7 +249,6 @@ class Server:
             (None if self.shard_seq else "data", None), (b, 1))
         tok_spec = P(*spec)
         return jax.jit(self.model.decode_step,
-                       in_shardings=jit_shardings(self.mesh,
-                                                  (pspec, tok_spec, cspec)),
-                       out_shardings=jit_shardings(self.mesh, (None, cspec)),
+                       in_shardings=(pspec, tok_spec, cspec),
+                       out_shardings=(None, cspec),
                        donate_argnums=(2,))

@@ -264,7 +264,7 @@ class BatchedEngine:
             ab = as_buffer(a, self.spec)
             bb = as_buffer(b, self.spec)
             return bool(jnp.allclose(ab, bb, rtol=1e-6, atol=1e-6))
-        except Exception:
+        except (ValueError, TypeError):      # layouts or dtypes differ
             return False
 
     def aggregate(self, models, weights=None):
@@ -310,7 +310,7 @@ class BatchedEngine:
             x0 = group[0][1][0][0]
             step_elems = len(group) * int(np.prod(x0.shape))
             width = len(group) if step_elems <= _SCAN_VOLUME \
-                else _MAX_VMAP_WIDTH
+                else _max_vmap_width()
             for lo in range(0, len(group), width):
                 self._run_group(group[lo:lo + width])
 
@@ -412,8 +412,13 @@ class MeshEngine(BatchedEngine):
 # Per-step element-count threshold below which the whole cohort round is
 # one fused scan dispatch instead of one dispatch per batch index.
 _SCAN_VOLUME = 65536
-# Widest vmapped model batch per dispatch (see _flush).
-_MAX_VMAP_WIDTH = 16 if jax.default_backend() == "tpu" else 3
+
+
+def _max_vmap_width() -> int:
+    """Widest vmapped model batch per dispatch (see _flush). Asked at
+    flush time, not import time: importing the engine must not claim a
+    device."""
+    return 16 if jax.default_backend() == "tpu" else 3
 
 
 def _cohort_ops(task, shardings=None):

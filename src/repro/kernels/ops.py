@@ -14,7 +14,7 @@ import numpy as np
 
 from repro.engine.flat import FlatModel, FlatSpec, as_buffer
 from repro.kernels.aggregate import TILE, aggregate_tiles
-from repro.kernels.fused import (SUBTILE, aggregate_flat_onepass,
+from repro.kernels.fused import (SUBTILE, aggregate_flat_onepass, bits,
                                  aggregate_flat_onepass_sharded,
                                  aggregate_quantize_flat,
                                  aggregate_quantize_flat_sharded,
@@ -205,7 +205,7 @@ def masked_aggregate_flatmodel(models, weights=None, *, seeds, signs,
     _check_weights(weights)
     if spec is None:
         spec = models[0].spec
-    y = jnp.stack([as_buffer(m, spec) for m in models])
+    y = jnp.stack([bits(as_buffer(m, spec)) for m in models])
     w = jnp.asarray(weights, jnp.float32)
     seeds = jnp.asarray(seeds, jnp.uint32)
     signs = jnp.asarray(signs, jnp.int32)

@@ -92,8 +92,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
     }
     t0 = time.time()  # noqa: DL002(lower/compile wall timing for the dry-run record)
 
-    from repro.utils.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             micro, b_micro = _micro_batch(arch, shape, policy.n_participants,
                                           micro_override)

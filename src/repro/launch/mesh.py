@@ -10,7 +10,13 @@ from __future__ import annotations
 import jax
 
 from repro.config import MeshConfig
-from repro.utils.compat import make_mesh
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types (classic GSPMD propagation;
+    jax's default is Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,9 +36,6 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 
 def make_mesh_from_config(mesh_cfg: MeshConfig):
-    # via the compat shim, NOT jax.make_mesh directly: jax 0.4.x (this
-    # container) has no jax.make_mesh, and the shim also picks Auto axis
-    # types where supported.
     return make_mesh(mesh_cfg.shape, mesh_cfg.axes)
 
 

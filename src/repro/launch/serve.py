@@ -63,7 +63,7 @@ def main():
     if not args.full_size:
         cfg = configs.reduced(cfg)
 
-    from repro.utils.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
 
     if args.devices:
         mp = args.model_parallel
@@ -83,7 +83,7 @@ def main():
     if cfg.family == "vlm":
         max_len += cfg.image_tokens * cfg.anyres_tiles
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = server.shard_params(server.model.init(jax.random.key(args.seed)))
         cache = server.shard_cache(server.model.init_cache(args.batch, max_len))
         batch = {"tokens": jax.random.randint(

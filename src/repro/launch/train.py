@@ -113,7 +113,7 @@ def run_mesh(args) -> None:
     model_par = args.model_parallel
     data_par = n_dev // model_par
     mesh_cfg = MeshConfig(multi_pod=False, data=data_par, model=model_par)
-    from repro.utils.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((data_par, model_par), ("data", "model"))
 
     cfg = configs.get_config(args.arch)
@@ -132,7 +132,7 @@ def run_mesh(args) -> None:
                         vocab=cfg.vocab, seed=args.seed)
     rng = np.random.default_rng(args.seed)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(args.seed)
         step = trainer.jit_train_step(
             batch_template=jax.tree.map(
@@ -194,6 +194,8 @@ def main():
     ap.add_argument("--failure-rate", type=float, default=0.0)
     ap.add_argument("--full-size", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "sim":
         run_sim(args)
     else:

@@ -160,12 +160,13 @@ def test_restore_with_sharding_pytree(tmp_path):
 
 def test_restore_flatmodel_with_flat_shardings(tmp_path):
     from repro.sharding import flat_shardings
-    from repro.utils.compat import make_mesh
 
     fm = FlatModel.pack({"w": jnp.arange(6, dtype=jnp.float32)})
     path = str(tmp_path / "fmsh")
     checkpoint.save(path, fm)
-    sh = flat_shardings(make_mesh((1, 1), ("data", "model")))
+    sh = flat_shardings(jax.make_mesh(
+        (1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2))
     back, _ = checkpoint.restore(path, fm, shardings=sh)
     assert isinstance(back, FlatModel)
     assert back.buffer.sharding == sh.vec

@@ -21,8 +21,9 @@ def run_in_subprocess(body: str) -> str:
         from repro import configs
         from repro.config import MeshConfig, TrainConfig
         from repro.core.distributed import DistributedTrainer, Server
-        from repro.utils.compat import make_mesh, set_mesh
-        mesh = make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         mesh_cfg = MeshConfig(data=4, model=2)
     """) + textwrap.dedent(body)
     proc = subprocess.run([sys.executable, "-c", code],
@@ -40,7 +41,7 @@ def test_modest_round_step_trains_and_masks():
                                      donate=False)
         P = trainer.policy.n_participants
         assert P == 4
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = trainer.init_state(0)
             B, S = 2, 32
             tmpl = {k: jax.ShapeDtypeStruct((P, 1, B, S), jnp.int32)
@@ -74,7 +75,7 @@ def test_dsgd_step_keeps_replica_divergence():
                                      mesh_cfg, strategy="dsgd", mesh=mesh,
                                      donate=False)
         P = trainer.policy.n_participants
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = trainer.init_state(0)
             B, S = 2, 32
             tmpl = {k: jax.ShapeDtypeStruct((P, 1, B, S), jnp.int32)
@@ -100,7 +101,7 @@ def test_serve_sharded_prefill_decode():
     out = run_in_subprocess("""
         cfg = configs.reduced(configs.get_config("gemma2-27b"))
         server = Server(cfg, mesh_cfg, mesh=mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = server.shard_params(server.model.init(jax.random.key(0)))
             cache = server.shard_cache(server.model.init_cache(4, 24))
             batch = {"tokens": np.random.default_rng(1).integers(

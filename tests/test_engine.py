@@ -3,6 +3,10 @@ aggregation (incl. the fused aggregate→quantize kernel and the ≤2
 pallas_call regression guard), vmapped-vs-sequential cohort trajectory
 parity, the ragged-tail loss-mask semantics, and session integration."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,6 +177,21 @@ def test_onepass_kernel_count(task, monkeypatch):
     aggregate_flatmodel(models, [1.0, 1.0], spec=task.flat_spec,
                         quantize=True, use_kernel=True, interpret=True)
     assert counts["oneq"] == 1 and counts["one"] == 1
+
+
+def test_import_initialises_no_backend():
+    """Importing the engine, kernels, tasks and sessions must not
+    initialise a JAX backend: on a TPU host that would claim the chip
+    for a process that may only be a parent of the one that needs it."""
+    code = ("import repro.engine, repro.kernels, repro.models.tasks, "
+            "repro.sim.runner, repro.launch.mesh\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120,
+                          env={**os.environ, "PYTHONPATH": src})
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 # ------------------------------------------------------- cohort training

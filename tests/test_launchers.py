@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 ENV = {**os.environ, "PYTHONPATH": SRC}
 
@@ -15,6 +17,25 @@ def run_cli(args, timeout=400):
                           env=ENV, cwd=os.path.join(SRC, ".."))
     assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
     return proc.stdout
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """Entry points cache compiles where JAX_COMPILATION_CACHE_DIR says,
+    else in the fixed .jax_cache/ at the repo root."""
+    env = {k: v for k, v in ENV.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120, env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    want = str(tmp_path) if env_dir else os.path.abspath(
+        os.path.join(SRC, "..", ".jax_cache"))
+    assert proc.stdout.split() == [want, want]
 
 
 def test_train_sim_modest(tmp_path):

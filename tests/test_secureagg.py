@@ -178,6 +178,32 @@ def test_masked_aggregate_bit_identical_to_plain(spec, quantize, use_kernel):
                               np.asarray(masked.buffer))
 
 
+@pytest.mark.parametrize("quantize", [False, True])
+def test_masked_rows_reach_the_kernel_as_uint32(spec, quantize, monkeypatch):
+    """Sealed words include NaN bit patterns, which an fp32 copy on the
+    TPU need not keep (a v5e rewrote 2141 of 273,344 words of an fp32
+    stack): the aggregator must stack and hand them over as uint32."""
+    import repro.kernels.ops as ops
+
+    name = ("unmask_aggregate_quantize_flat" if quantize
+            else "unmask_aggregate_flat")
+    real = getattr(ops, name)
+    seen = []
+
+    def spy(y, *a, **k):
+        seen.append(y.dtype)
+        return real(y, *a, **k)
+
+    monkeypatch.setattr(ops, name, spy)
+    masker = PairwiseMasker(0)
+    _, sealed, secrets = _sealed(spec, masker)
+    seeds, signs = masker.unmask_matrices(sealed, secrets)
+    masked_aggregate_flatmodel([sm.payload for sm in sealed], seeds=seeds,
+                               signs=signs, spec=spec, quantize=quantize,
+                               use_kernel=True, interpret=True)
+    assert seen == [jnp.uint32]
+
+
 def test_masked_aggregate_sharded_dispatch_bit_identical(spec):
     """Sharded path on a 1×1 mesh (buildable anywhere): same bits as the
     unsharded plain path. The CI sharded job and sharded_child.py rerun

@@ -31,8 +31,8 @@ from repro.config import MeshConfig  # noqa: E402
 from repro.core.tasks import AbstractTask  # noqa: E402
 from repro.engine import BatchedEngine, MeshEngine, SequentialEngine, \
     make_engine  # noqa: E402
-from repro.kernels.fused import SUBTILE, _VMEM_BUDGET, shard_align, \
-    tile_for  # noqa: E402
+from repro.kernels.fused import SUBTILE, _VMEM_BUDGET, _VMEM_CAP, \
+    _vmem_limit, block_bytes, shard_align, tile_for  # noqa: E402
 from repro.kernels.ops import aggregate_flatmodel  # noqa: E402
 from repro.models.tasks import cnn_task  # noqa: E402
 from repro.sharding import FlatShardings, ShardingPolicy  # noqa: E402
@@ -58,16 +58,19 @@ def task():
 def test_tile_for_pinned_choices(task):
     """Pin chosen tiles so tiling changes are deliberate, not incidental.
 
-    The budget is divided by 2·4·P: two (P, tile) fp32 blocks in flight
-    (double-buffered), which the pre-fix code ignored (it fit only one).
+    The budget covers every double-buffered block of a grid step
+    (:func:`block_bytes`: the P-row stack, int mask, mean, codes and
+    scales), not only the stack: counting the stack alone picked tiles
+    the TPU compiler refused for lack of VMEM.
     """
     n = task.flat_spec.n                      # paper CNN: 136 672
     assert n == 136672
     assert tile_for(n, 5) == 147456           # need-capped: 9 subtiles
-    assert tile_for(n, 8) == 98304            # budget-capped: 6 subtiles
+    assert tile_for(n, 8) == 147456           # need-capped: 9 subtiles
+    assert tile_for(n, 10) == 114688          # budget-capped: 7 subtiles
     # a large config (transformer-scale flat buffer)
-    assert tile_for(50_000_000, 8) == 98304
-    assert tile_for(50_000_000, 2) == 393216
+    assert tile_for(50_000_000, 8) == 147456
+    assert tile_for(50_000_000, 2) == 360448
     assert tile_for(147456, 64) == SUBTILE    # floor at one subtile
 
 
@@ -82,6 +85,16 @@ def test_tile_for_respects_budget(n, p):
     assert tile <= -(-n // SUBTILE) * SUBTILE
 
 
+@pytest.mark.parametrize("p", [1, 2, 5, 10, 64])
+@pytest.mark.parametrize("n", [SUBTILE, 136672, 2 ** 22])
+def test_tile_for_counts_every_block(n, p):
+    """Every double-buffered block of a grid step fits the budget, and
+    the scoped VMEM limit passed to the compiler covers them."""
+    tile = tile_for(n, p)
+    assert tile == SUBTILE or block_bytes(tile, p) <= _VMEM_BUDGET
+    assert block_bytes(tile, p) < _vmem_limit(tile, p) <= _VMEM_CAP
+
+
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
 @pytest.mark.parametrize("n", [1, 100, SUBTILE, 8 * SUBTILE - 1, 136672])
 def test_shard_align(n, shards):
@@ -93,14 +106,14 @@ def test_shard_align(n, shards):
 
 
 # ---------------------------------------------------------------------------
-# mesh construction (satellite: route through the compat shim)
+# mesh construction (every path builds its mesh with launch.mesh.make_mesh)
 # ---------------------------------------------------------------------------
 
 
 def test_mesh_construction_routes_through_compat(monkeypatch):
-    """Every mesh path must go through repro.utils.compat.make_mesh (jax
-    0.4.x has no ``axis_types``; calling jax.make_mesh directly bypassed
-    the shim). Recorded without touching real device state."""
+    """Every mesh path must go through ``repro.launch.mesh.make_mesh``,
+    which asks for Auto axis types (``jax.make_mesh`` defaults to
+    Explicit). Recorded without touching real device state."""
     import repro.launch.mesh as lm
 
     calls = []
