@@ -1,0 +1,115 @@
+"""The harness finds every configuration, traffic mix and per-layer metric
+by its name in BENCHMARK.json, and a new one is added by adding files and
+entries alone."""
+
+import json
+import os
+import re
+import shutil
+
+import pytest
+
+import harness
+
+M = harness.manifest()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+
+def test_every_name_resolves():
+    for c in M["configs"]:
+        assert os.path.isfile(os.path.join(harness.REPO, c["file"]))
+        assert harness.load_json("configs", c["name"])["name"] == c["name"]
+    for w in M["workloads"]:
+        traffic = harness.load_json("traffic", w["traffic"])
+        config = harness.load_json("configs", w["config"])
+        harness.load_module("sessions", traffic["session"])
+        harness.load_module("profiles", traffic["profile"]["kind"])
+        harness.load_module("datasets", config["dataset"]["kind"])
+        harness.load_module("reference", config["model"]["family"])
+        assert set(harness.readers(M, w["name"])) == {
+            x["name"] for x in M["per_layer"]
+            if w["name"] in x.get("workloads", [w["name"]])}
+
+
+def test_manifest_shape():
+    assert set(M) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    metrics = M["end_to_end"] + M["per_layer"]
+    names = [x["name"] for x in metrics + M["workloads"] + M["configs"]]
+    assert all(NAME.match(n) for n in names)
+    assert len({x["name"] for x in metrics}) == len(metrics)
+    assert len({(w["config"], w["traffic"]) for w in M["workloads"]}) == \
+        len(M["workloads"])
+    e2e = {x["name"] for x in M["end_to_end"]}
+    assert "setup_s" in e2e
+    for x in metrics:
+        assert UNIT.match(x["unit"]) and x["better"] in ("lower", "higher")
+    for x in M["end_to_end"]:
+        assert set(x) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert 0.01 <= x["bound"] <= 0.25
+        assert x["source"] in ("host_clock", "device_trace")
+    for x in M["per_layer"]:
+        assert set(x) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert x["moves"] in e2e
+        assert x["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for w in M["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["chips"] == 1 and len(w["why"]) <= 200
+    assert len(json.dumps(M)) < 64 * 1024
+
+
+def test_a_new_cell_is_files_and_entries(tmp_path):
+    """Copy the benchmark, add a configuration, a traffic mix and a
+    per-layer metric as new files, and list them in the manifest: the
+    harness finds them, and no file that was there changes."""
+    root = tmp_path / "repo"
+    chip = root / "benchmarks" / "chip"
+    shutil.copytree(harness.HERE, chip,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
+    m = json.loads(json.dumps(M))
+    config = harness.load_json("configs", "paper-cnn")
+    config["name"] = "paper-cnn-wide"
+    (chip / "configs" / "paper-cnn-wide.json").write_text(json.dumps(config))
+    traffic = harness.load_json("traffic", "modest-diurnal-n100")
+    traffic["sample_size"] = 20
+    (chip / "traffic" / "modest-wide.json").write_text(json.dumps(traffic))
+    (chip / "layers" / "rounds_seen.py").write_text(
+        "def read(run):\n    return float(run.window.rounds)\n")
+    m["configs"].append(dict(m["configs"][0], name="paper-cnn-wide",
+                             file="benchmarks/chip/configs/paper-cnn-wide.json"))
+    m["workloads"].append({"name": "cnn-wide", "config": "paper-cnn-wide",
+                           "traffic": "modest-wide", "chips": 1,
+                           "why": "a test cell"})
+    m["per_layer"].append({"name": "rounds_seen", "unit": "rounds",
+                           "better": "higher", "source": "host_clock",
+                           "layer": "session", "moves": "wall_s_per_round",
+                           "workloads": ["cnn-wide"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+
+    got = harness.manifest(str(root))
+    w = harness.workload(got, "cnn-wide")
+    assert harness.load_json("configs", w["config"], str(chip))["name"] \
+        == "paper-cnn-wide"
+    assert harness.load_json("traffic", w["traffic"],
+                             str(chip))["sample_size"] == 20
+    found = harness.readers(got, "cnn-wide", str(chip))
+    assert set(found) == {"rounds_seen"}
+
+    class Win:
+        rounds = 7
+    assert found["rounds_seen"](type("Run", (), {"window": Win})) == 7.0
+    assert "rounds_seen" not in harness.readers(got, "cnn-modest-diurnal",
+                                                str(chip))
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_an_unknown_name_is_an_error():
+    with pytest.raises(KeyError):
+        harness.workload(M, "no-such-cell")
+    with pytest.raises(KeyError):
+        harness.load_json("configs", "no-such-config")
