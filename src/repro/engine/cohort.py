@@ -36,10 +36,12 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.engine.flat import FlatModel, as_buffer, as_tree
 from repro.engine.lowering import masked_loss_for
 from repro.engine.optim_flat import build_flat
+from repro.utils import spans
 
 
 class SequentialEngine:
@@ -107,6 +109,7 @@ class BatchedEngine:
     """Flat-model vmapped cohort trainer for a :class:`JaxTask`."""
 
     name = "batched"
+    shardings = None            # MeshEngine: the flat buffers' mesh layout
 
     def __init__(self, task):
         self.task = task
@@ -122,8 +125,11 @@ class BatchedEngine:
         # The jitted step is cached on the task: new engines (one per
         # session) must not retrace — compilation is paid once per task.
         self._opt, self._step, self._scan = _cohort_ops(task)
-        self.flushes = 0            # introspection for tests/benchmarks
-        self.jobs_run = 0
+        # introspection for tests/benchmarks
+        self.flushes = 0            # vmapped groups run
+        self.jobs_run = 0           # jobs trained in them
+        self.jobs_served = 0        # result() calls answered from a flush
+        self.batch_bytes_h2d = 0    # bytes of the batches copied to device
 
     # ------------------------------------------------------------------ api
 
@@ -178,6 +184,13 @@ class BatchedEngine:
 
     def result(self, node_id, tag, params, client, *, batch_size, epochs,
                seed, lr_scale: float = 1.0):
+        with TraceAnnotation(spans.ENGINE_RESULT):
+            return self._result(node_id, tag, params, client,
+                                batch_size=batch_size, epochs=epochs,
+                                seed=seed, lr_scale=lr_scale)
+
+    def _result(self, node_id, tag, params, client, *, batch_size, epochs,
+                seed, lr_scale):
         hp = (batch_size, epochs, seed)
         hit = self._lookup(node_id, tag, params, hp)
         if hit is None and any(j.node_id == node_id and j.tag == tag
@@ -193,6 +206,7 @@ class BatchedEngine:
             hit = self._lookup(node_id, tag, params, hp)
         if hit is not None:
             self._served.add((node_id, tag))
+            self.jobs_served += 1
             return hit
         self._served.add((node_id, tag))
         return self.task.local_train(params, client, batch_size=batch_size,
@@ -269,14 +283,19 @@ class BatchedEngine:
 
     def aggregate(self, models, weights=None):
         """Whole-model one-pass aggregation (stays flat: FlatModel out)."""
-        return self.task.aggregate(models, weights)
+        with TraceAnnotation(spans.ENGINE_AGGREGATE, models=len(models)):
+            return self.task.aggregate(models, weights,
+                                       shardings=self.shardings)
 
     def aggregate_masked(self, models, seeds, signs, weights=None):
         """Fused unmask→aggregate over sealed FlatModels (secure agg)."""
-        return self.task.aggregate_masked(models, seeds, signs, weights)
+        with TraceAnnotation(spans.ENGINE_AGGREGATE, models=len(models)):
+            return self.task.aggregate_masked(models, seeds, signs, weights,
+                                              shardings=self.shardings)
 
     def evaluate_models(self, models, test):
-        return self.task.evaluate_many(models, test)
+        with TraceAnnotation(spans.ENGINE_EVALUATE, models=len(models)):
+            return self.task.evaluate_many(models, test)
 
     # ----------------------------------------------------------------- flush
 
@@ -289,17 +308,18 @@ class BatchedEngine:
         # client from riding along through masked no-op steps (non-IID
         # partitions make shard sizes — and so step counts — ragged).
         groups: Dict[Tuple[int, int, int], List[Tuple[_Job, list]]] = {}
-        for j in jobs:
-            batches = self.task._padded_batches(j.client, j.batch_size,
-                                                seed=j.seed, epochs=j.epochs)
-            if not batches:                   # empty shard: training is a
-                self._done[j.key] = (         # no-op, like the sequential
-                    FlatModel(as_buffer(j.params, self.spec),  # path
-                              self._out_spec(j.params)),
-                    j.params, j.confirmed, j.hp)
-                continue
-            groups.setdefault((j.batch_size, j.epochs, len(batches)),
-                              []).append((j, batches))
+        with TraceAnnotation(spans.ENGINE_ASSEMBLE, jobs=len(jobs)):
+            for j in jobs:
+                batches = self.task._padded_batches(
+                    j.client, j.batch_size, seed=j.seed, epochs=j.epochs)
+                if not batches:               # empty shard: training is a
+                    self._done[j.key] = (     # no-op, like the sequential
+                        FlatModel(as_buffer(j.params, self.spec),  # path
+                                  self._out_spec(j.params)),
+                        j.params, j.confirmed, j.hp)
+                    continue
+                groups.setdefault((j.batch_size, j.epochs, len(batches)),
+                                  []).append((j, batches))
         for group in groups.values():
             # Cap the vmap width in the big-compute regime: on the CPU
             # backend the per-model cost of the vmapped step rises past
@@ -322,30 +342,35 @@ class BatchedEngine:
         per_job = [b for _, b in pairs]
         T = max(len(b) for b in per_job)
         x0, y0 = per_job[0][0][0], per_job[0][0][1]
-        xs = np.zeros((T, S) + x0.shape, x0.dtype)
-        ys = np.zeros((T, S) + y0.shape, y0.dtype)
-        ms = np.zeros((T, S, x0.shape[0]), np.float32)
-        act = np.zeros((T, S), np.bool_)
-        for s, batches in enumerate(per_job):
-            for t, (x, y, m) in enumerate(batches):
-                xs[t, s], ys[t, s], ms[t, s], act[t, s] = x, y, m, True
+        with TraceAnnotation(spans.ENGINE_ASSEMBLE, jobs=S):
+            xs = np.zeros((T, S) + x0.shape, x0.dtype)
+            ys = np.zeros((T, S) + y0.shape, y0.dtype)
+            ms = np.zeros((T, S, x0.shape[0]), np.float32)
+            act = np.zeros((T, S), np.bool_)
+            for s, batches in enumerate(per_job):
+                for t, (x, y, m) in enumerate(batches):
+                    xs[t, s], ys[t, s], ms[t, s], act[t, s] = x, y, m, True
+        self.batch_bytes_h2d += xs.nbytes + ys.nbytes + ms.nbytes + act.nbytes
 
-        buf = self._place(jnp.stack([as_buffer(j.params, self.spec)
-                                     for j in jobs]))
-        state = self._opt.init(buf)
-        # Form selection (both are the same step math): small per-step
-        # volume → one fused scan dispatch for the whole cohort round;
-        # large volume → one dispatch per batch index (XLA-CPU pessimizes
-        # big conv bodies inside while-loops, measured ~2× slower).
-        if xs[0].size <= _SCAN_VOLUME and T > 1:
-            buf = self._scan(buf, state, jnp.asarray(xs), jnp.asarray(ys),
-                             jnp.asarray(ms), jnp.asarray(act))
-        else:
-            for t in range(T):
-                buf, state = self._step(buf, state, jnp.asarray(xs[t]),
-                                        jnp.asarray(ys[t]),
-                                        jnp.asarray(ms[t]),
-                                        jnp.asarray(act[t]))
+        with TraceAnnotation(spans.ENGINE_DISPATCH, steps=T):
+            buf = self._place(jnp.stack([as_buffer(j.params, self.spec)
+                                         for j in jobs]))
+            state = self._opt.init(buf)
+            # Form selection (both are the same step math): small per-step
+            # volume → one fused scan dispatch for the whole cohort round;
+            # large volume → one dispatch per batch index (XLA-CPU
+            # pessimizes big conv bodies inside while-loops, measured ~2×
+            # slower).
+            if xs[0].size <= _SCAN_VOLUME and T > 1:
+                buf = self._scan(buf, state, jnp.asarray(xs),
+                                 jnp.asarray(ys), jnp.asarray(ms),
+                                 jnp.asarray(act))
+            else:
+                for t in range(T):
+                    buf, state = self._step(buf, state, jnp.asarray(xs[t]),
+                                            jnp.asarray(ys[t]),
+                                            jnp.asarray(ms[t]),
+                                            jnp.asarray(act[t]))
         for s, j in enumerate(jobs):
             self._done[j.key] = (FlatModel(buf[s], self._out_spec(j.params)),
                                  j.params, j.confirmed, j.hp)
@@ -400,14 +425,6 @@ class MeshEngine(BatchedEngine):
     def _place(self, buf):
         return jax.device_put(buf, self.shardings.stack)
 
-    def aggregate(self, models, weights=None):
-        return self.task.aggregate(models, weights,
-                                   shardings=self.shardings)
-
-    def aggregate_masked(self, models, seeds, signs, weights=None):
-        return self.task.aggregate_masked(models, seeds, signs, weights,
-                                          shardings=self.shardings)
-
 
 # Per-step element-count threshold below which the whole cohort round is
 # one fused scan dispatch instead of one dispatch per batch index.
@@ -461,20 +478,24 @@ def _cohort_ops(task, shardings=None):
         rep = lambda b: jax.lax.with_sharding_constraint(   # noqa: E731
             b, shardings.replicated)
 
+    def grad_one(p, x, y, m):
+        return jax.grad(loss)(p, to_batch(x, y, m))
+
     def step(buf, state, xb, yb, mb, active):
-        ptree = spec.unpack_stacked(rep(buf))
-
-        def grad_one(p, x, y, m):
-            return jax.grad(loss)(p, to_batch(x, y, m))
-
-        gtree = jax.vmap(grad_one)(ptree, xb, yb, mb)
-        g = rep(spec.pack_stacked(gtree))
-        upd, nstate = opt_update(g, state, buf)
-        keep = active[:, None]
-        nbuf = pin(jnp.where(keep, buf + upd, buf))
-        nstate = {k: (pin(jnp.where(keep, v, state[k])) if v.ndim == 2
-                      else jnp.where(active, v, state[k]))
-                  for k, v in nstate.items()}
+        # the scopes name the step's ops in the device trace (utils/spans)
+        with jax.named_scope(spans.STEP_UNPACK):
+            ptree = spec.unpack_stacked(rep(buf))
+        with jax.named_scope(spans.STEP_GRAD):
+            gtree = jax.vmap(grad_one)(ptree, xb, yb, mb)
+        with jax.named_scope(spans.STEP_PACK):
+            g = rep(spec.pack_stacked(gtree))
+        with jax.named_scope(spans.STEP_OPTIMIZER):
+            upd, nstate = opt_update(g, state, buf)
+            keep = active[:, None]
+            nbuf = pin(jnp.where(keep, buf + upd, buf))
+            nstate = {k: (pin(jnp.where(keep, v, state[k])) if v.ndim == 2
+                          else jnp.where(active, v, state[k]))
+                      for k, v in nstate.items()}
         return nbuf, nstate
 
     def train_scan(buf, state, xs, ys, ms, act):

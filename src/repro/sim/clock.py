@@ -29,6 +29,10 @@ import itertools
 import warnings
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
+from repro.utils.spans import SIM_EVENT
+
 
 class _Rec:
     """Mutable per-event record (the heap entries are immutable tuples)."""
@@ -185,7 +189,8 @@ class Simulator:
                 continue
             self.now = t
             self.events_processed += 1
-            rec.fn()
+            with TraceAnnotation(SIM_EVENT):
+                rec.fn()
         if until is not None and self.now < until:
             self.now = until
 

@@ -1,12 +1,10 @@
-"""Minimal structured loggers (CSV + JSONL) used by benchmarks and drivers."""
+"""A minimal structured logger (CSV), used by ``launch/train.py``."""
 
 from __future__ import annotations
 
 import csv
-import json
 import os
 import sys
-import time
 from typing import IO, Optional
 
 
@@ -42,16 +40,3 @@ class CSVLogger:
         if self._fh is not None and self._fh is not sys.stdout:
             self._fh.close()
 
-
-class JSONLLogger:
-    def __init__(self, path: str):
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        self._fh = open(path, "a")
-
-    def log(self, **record):
-        record.setdefault("t", time.time())
-        self._fh.write(json.dumps(record, default=str) + "\n")
-        self._fh.flush()
-
-    def close(self):
-        self._fh.close()

@@ -223,6 +223,35 @@ def test_cohort_matches_sequential_fp32(task, small_clients):
         assert _max_err(s, g.tree) < 5e-4
 
 
+def test_counters_served_and_batch_bytes(task, small_clients):
+    """``jobs_served`` counts the result() calls a flush answered: a
+    planned job no node claims is trained (``jobs_run``) but never served.
+    ``batch_bytes_h2d`` is the size of the batches, labels, masks and
+    active flags each group copies to the device."""
+    params = task.init_params(0)
+    engine = BatchedEngine(task)
+    for i, c in enumerate(small_clients):
+        engine.register_client(str(i), c)
+    # clients 0 and 1 (25 and 40 samples) both take 2 steps: one group
+    engine.plan_cohort(1, ["0", "1"], params, batch_size=20, epochs=1,
+                       seed=11)
+    engine.result("0", 1, params, small_clients[0], batch_size=20, epochs=1,
+                  seed=11)
+    assert (engine.flushes, engine.jobs_run, engine.jobs_served) == (1, 2, 1)
+    c = small_clients[0]
+    T, S, B = 2, 2, 20
+    want = T * S * B * (c.x[0].nbytes + c.y[:1].nbytes + 4) + T * S
+    assert engine.batch_bytes_h2d == want
+    # a job never planned is trained alone and served: one more group
+    engine.result("2", 1, params, small_clients[2], batch_size=20, epochs=1,
+                  seed=11)
+    assert (engine.flushes, engine.jobs_run, engine.jobs_served) == (2, 3, 2)
+    assert engine.batch_bytes_h2d == want + 1 * 1 * B * (
+        c.x[0].nbytes + c.y[:1].nbytes + 4) + 1
+    # client 1's planned job is never claimed: run, not served
+    assert engine.jobs_served < engine.jobs_run
+
+
 def test_cohort_matches_sequential_bf16(small_clients):
     """bf16 tier: the sequential path re-rounds params to bf16 every step
     while the engine trains in fp32 and rounds once at the boundary, so
