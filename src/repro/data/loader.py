@@ -16,16 +16,19 @@ class ClientDataset:
     def __len__(self):
         return len(self.x)
 
-    def batches(self, batch_size: int, *, seed: int = 0, epochs: int = 1):
-        """One pass (E epochs) over the local data, the paper's E=1 default."""
+    def batch_indices(self, batch_size: int, *, seed: int = 0,
+                      epochs: int = 1):
+        """The sample indices of each batch of :meth:`batches`, in order."""
         rng = np.random.default_rng(seed)
         for _ in range(epochs):
             order = rng.permutation(len(self.x))
             for lo in range(0, len(order), batch_size):
-                sel = order[lo:lo + batch_size]
-                if len(sel) == 0:
-                    continue
-                yield self.x[sel], self.y[sel]
+                yield order[lo:lo + batch_size]
+
+    def batches(self, batch_size: int, *, seed: int = 0, epochs: int = 1):
+        """One pass (E epochs) over the local data, the paper's E=1 default."""
+        for sel in self.batch_indices(batch_size, seed=seed, epochs=epochs):
+            yield self.x[sel], self.y[sel]
 
     def sample_batch(self, batch_size: int, *, seed: int = 0):
         rng = np.random.default_rng(seed)

@@ -17,6 +17,11 @@ queued (or whose θ doesn't match the queued one, e.g. a second aggregator
 won the race with a different partial average) falls back to the
 sequential path — correctness never depends on the cache.
 
+Data path: each client's shard goes to the device once and stays there,
+cached on the task (so later sessions' engines find it). A flush builds
+only each job's sample indices and loss masks on the host; one program
+per group gathers the batches from the shards on the device.
+
 Batching semantics (the ragged-tail fix, shared with the sequential
 path): client batches are padded to a uniform shape with a per-row loss
 mask — masked rows contribute exactly zero gradient, unlike the old
@@ -30,6 +35,7 @@ batches on TPU) exact by construction.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -129,13 +135,14 @@ class BatchedEngine:
         self.flushes = 0            # vmapped groups run
         self.jobs_run = 0           # jobs trained in them
         self.jobs_served = 0        # result() calls answered from a flush
-        self.batch_bytes_h2d = 0    # bytes of the batches copied to device
+        self.batch_bytes_h2d = 0    # training-input bytes copied to device
+        self.shard_uploads = 0      # client shards this engine copied
 
     # ------------------------------------------------------------------ api
 
     def register_client(self, node_id, client) -> None:
-        """Teach the engine a node's shard so ``plan_cohort`` can build
-        that node's batches (sessions call this for every node)."""
+        """Teach the engine a node's shard so ``plan_cohort`` can queue
+        that node's trainings (sessions call this for every node)."""
         self._clients[node_id] = client
 
     def plan_cohort(self, tag, node_ids, params, *, batch_size, epochs,
@@ -307,52 +314,49 @@ class BatchedEngine:
         # shapes must agree, and bucketing by step count keeps a short
         # client from riding along through masked no-op steps (non-IID
         # partitions make shard sizes — and so step counts — ragged).
-        groups: Dict[Tuple[int, int, int], List[Tuple[_Job, list]]] = {}
+        groups: Dict[Tuple[int, int, int], List[Tuple[_Job, tuple]]] = {}
         with TraceAnnotation(spans.ENGINE_ASSEMBLE, jobs=len(jobs)):
             for j in jobs:
-                batches = self.task._padded_batches(
-                    j.client, j.batch_size, seed=j.seed, epochs=j.epochs)
-                if not batches:               # empty shard: training is a
+                plan = _batch_plan(j.client, j.batch_size, seed=j.seed,
+                                   epochs=j.epochs)
+                if not len(plan[0]):          # empty shard: training is a
                     self._done[j.key] = (     # no-op, like the sequential
                         FlatModel(as_buffer(j.params, self.spec),  # path
                                   self._out_spec(j.params)),
                         j.params, j.confirmed, j.hp)
                     continue
-                groups.setdefault((j.batch_size, j.epochs, len(batches)),
-                                  []).append((j, batches))
-        for group in groups.values():
+                groups.setdefault((j.batch_size, j.epochs, len(plan[0])),
+                                  []).append((j, plan))
+        for (batch_size, _, _), group in groups.items():
             # Cap the vmap width in the big-compute regime: on the CPU
             # backend the per-model cost of the vmapped step rises past
             # S≈3 (batch-grouped conv lowering), so wide cohorts run as a
             # few medium chunks. Small per-step volumes take the fused
             # scan path instead, which handles full width well. TPUs want
             # the full width everywhere; the cap is backend-tuned.
-            x0 = group[0][1][0][0]
-            step_elems = len(group) * int(np.prod(x0.shape))
+            step_elems = len(group) * _batch_elems(group[0][0].client,
+                                                   batch_size)
             width = len(group) if step_elems <= _SCAN_VOLUME \
                 else _max_vmap_width()
             for lo in range(0, len(group), width):
                 self._run_group(group[lo:lo + width])
 
-    def _run_group(self, pairs: List[Tuple[_Job, list]]) -> None:
+    def _run_group(self, pairs: List[Tuple[_Job, tuple]]) -> None:
         jobs = [j for j, _ in pairs]
         self.flushes += 1
         self.jobs_run += len(jobs)
-        S = len(jobs)
-        per_job = [b for _, b in pairs]
-        T = max(len(b) for b in per_job)
-        x0, y0 = per_job[0][0][0], per_job[0][0][1]
+        S, B = len(jobs), jobs[0].batch_size
+        T = max(len(idx) for _, (idx, _) in pairs)
         with TraceAnnotation(spans.ENGINE_ASSEMBLE, jobs=S):
-            xs = np.zeros((T, S) + x0.shape, x0.dtype)
-            ys = np.zeros((T, S) + y0.shape, y0.dtype)
-            ms = np.zeros((T, S, x0.shape[0]), np.float32)
+            idx = np.zeros((T, S, B), np.int32)
+            ms = np.zeros((T, S, B), np.float32)
             act = np.zeros((T, S), np.bool_)
-            for s, batches in enumerate(per_job):
-                for t, (x, y, m) in enumerate(batches):
-                    xs[t, s], ys[t, s], ms[t, s], act[t, s] = x, y, m, True
-        self.batch_bytes_h2d += xs.nbytes + ys.nbytes + ms.nbytes + act.nbytes
+            for s, (_, (i, m)) in enumerate(pairs):
+                idx[:len(i), s], ms[:len(i), s], act[:len(i), s] = i, m, True
+        self.batch_bytes_h2d += idx.nbytes + ms.nbytes + act.nbytes
 
         with TraceAnnotation(spans.ENGINE_DISPATCH, steps=T):
+            shards = [self._shard(j.client, B) for j in jobs]
             buf = self._place(jnp.stack([as_buffer(j.params, self.spec)
                                          for j in jobs]))
             state = self._opt.init(buf)
@@ -361,19 +365,46 @@ class BatchedEngine:
             # large volume → one dispatch per batch index (XLA-CPU
             # pessimizes big conv bodies inside while-loops, measured ~2×
             # slower).
-            if xs[0].size <= _SCAN_VOLUME and T > 1:
-                buf = self._scan(buf, state, jnp.asarray(xs),
-                                 jnp.asarray(ys), jnp.asarray(ms),
-                                 jnp.asarray(act))
+            scan = S * _batch_elems(jobs[0].client, B) <= _SCAN_VOLUME \
+                and T > 1
+            batches = _stage(tuple(x for x, _ in shards),
+                             tuple(y for _, y in shards), self._put(idx),
+                             self._put(ms), self._put(act),
+                             per_step=not scan)
+            if scan:
+                buf = self._scan(buf, state, *batches)
             else:
-                for t in range(T):
-                    buf, state = self._step(buf, state, jnp.asarray(xs[t]),
-                                            jnp.asarray(ys[t]),
-                                            jnp.asarray(ms[t]),
-                                            jnp.asarray(act[t]))
+                for xb, yb, mb, ab in batches:
+                    buf, state = self._step(buf, state, xb, yb, mb, ab)
         for s, j in enumerate(jobs):
             self._done[j.key] = (FlatModel(buf[s], self._out_spec(j.params)),
                                  j.params, j.confirmed, j.hp)
+
+    def _shard(self, client, batch_size: int):
+        """``client``'s ``(x, y)`` on the device, rows zero-padded to whole
+        batches. Uploaded once and cached on the task, keyed by the
+        client's identity (the entry holds the client, so its id is not
+        reused); a client whose shape changed is uploaded again."""
+        rows = -(-len(client) // batch_size) * batch_size
+        cache = getattr(self.task, "_shard_cache", None)
+        if cache is None:
+            cache = self.task._shard_cache = {}
+        key = (self.shardings, id(client))
+        hit = cache.get(key)
+        if hit is not None and hit[0] is client and hit[1] == (
+                rows, client.x.shape, client.y.shape):
+            return hit[2]
+        shard = (self._put(_pad_rows(client.x, rows)),
+                 self._put(_pad_rows(client.y, rows)))
+        cache[key] = (client, (rows, client.x.shape, client.y.shape), shard)
+        self.shard_uploads += 1
+        self.batch_bytes_h2d += shard[0].nbytes + shard[1].nbytes
+        return shard
+
+    def _put(self, a):
+        """Host → device copy of a training input (the MeshEngine
+        replicates it over its mesh)."""
+        return jnp.asarray(a)
 
     def _place(self, buf):
         """Device-placement hook for the stacked ``(S, N)`` cohort buffer;
@@ -425,10 +456,59 @@ class MeshEngine(BatchedEngine):
     def _place(self, buf):
         return jax.device_put(buf, self.shardings.stack)
 
+    def _put(self, a):
+        # the gradients run on replicated leaves, so the shards (and the
+        # batches gathered from them) are replicated too
+        return jax.device_put(a, self.shardings.replicated)
+
 
 # Per-step element-count threshold below which the whole cohort round is
 # one fused scan dispatch instead of one dispatch per batch index.
 _SCAN_VOLUME = 65536
+
+
+def _batch_elems(client, batch_size: int) -> int:
+    """Elements of one client's input batch."""
+    return batch_size * int(np.prod(client.x.shape[1:]))
+
+
+def _batch_plan(client, batch_size: int, *, seed: int, epochs: int):
+    """``(idx, mask)``, each ``(T, B)``: the sample indices and loss mask
+    of the job's batches in the order ``ClientDataset.batches`` draws
+    them. A short last batch is padded as ``JaxTask._padded_batches`` pads
+    it: its own samples repeated, with mask 0."""
+    sels = list(client.batch_indices(batch_size, seed=seed, epochs=epochs))
+    idx = np.empty((len(sels), batch_size), np.int32)
+    mask = np.zeros((len(sels), batch_size), np.float32)
+    for t, sel in enumerate(sels):
+        idx[t] = np.resize(sel, batch_size)
+        mask[t, :len(sel)] = 1.0
+    return idx, mask
+
+
+def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
+    if len(a) == rows:
+        return a
+    pad = np.zeros((rows - len(a),) + a.shape[1:], a.dtype)
+    return np.concatenate([a, pad])
+
+
+@functools.partial(jax.jit, static_argnames=("per_step",))
+def _stage(xs, ys, idx, ms, act, *, per_step: bool):
+    """One group's batches, gathered on the device: member ``s`` takes
+    the rows ``idx[:, s]`` of its shard ``xs[s], ys[s]``. Returns the
+    ``(T, S, B, ...)`` inputs, labels, masks and ``(T, S)`` active flags
+    whole (scan form) or as one ``(S, B, ...)`` step's worth each (step
+    form).
+
+    The gather runs here and not inside the step: in the step the TPU
+    compiler converted the whole stacked shard to bfloat16 for the matrix
+    unit before gathering, 61 MB at every step for ten CIFAR shards."""
+    xb = jnp.stack([x[idx[:, s]] for s, x in enumerate(xs)], axis=1)
+    yb = jnp.stack([y[idx[:, s]] for s, y in enumerate(ys)], axis=1)
+    if not per_step:
+        return xb, yb, ms, act
+    return [(xb[t], yb[t], ms[t], act[t]) for t in range(idx.shape[0])]
 
 
 def _max_vmap_width() -> int:

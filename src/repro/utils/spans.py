@@ -7,12 +7,19 @@ span the host was in. Keyword arguments of a span become stats of its
 event. Device scopes are :func:`jax.named_scope` s: they name the ops of a
 compiled program in its ``op_name`` metadata and change no arithmetic.
 docs/ENGINE.md ("Tracing a session") shows how to capture a session.
+
+Counters of :class:`~repro.engine.cohort.BatchedEngine`, beside
+``flushes`` and ``jobs_run``: ``jobs_served`` (``result()`` calls answered
+from a flush), ``batch_bytes_h2d`` (bytes copied to the device for
+training inputs: each group's indices, masks and active flags, and the
+shards uploaded) and ``shard_uploads`` (client shards the engine copied
+to the device; the rest of its jobs ran from resident shards).
 """
 
 # host spans
 SIM_EVENT = "repro.sim.event"                # Simulator.run: one event's handler
 ENGINE_RESULT = "repro.engine.result"        # BatchedEngine.result
-ENGINE_ASSEMBLE = "repro.engine.assemble"    # a flush's numpy batches (jobs=)
+ENGINE_ASSEMBLE = "repro.engine.assemble"    # a flush's index build (jobs=)
 ENGINE_DISPATCH = "repro.engine.dispatch"    # a group's copies and dispatches (steps=)
 ENGINE_AGGREGATE = "repro.engine.aggregate"  # BatchedEngine.aggregate[_masked] (models=)
 ENGINE_EVALUATE = "repro.engine.evaluate"    # BatchedEngine.evaluate_models (models=)

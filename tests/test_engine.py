@@ -223,33 +223,138 @@ def test_cohort_matches_sequential_fp32(task, small_clients):
         assert _max_err(s, g.tree) < 5e-4
 
 
+def _fresh(clients):
+    """Copies of ``clients`` that no task has cached on the device yet."""
+    return [ClientDataset(c.x.copy(), c.y.copy()) for c in clients]
+
+
 def test_counters_served_and_batch_bytes(task, small_clients):
     """``jobs_served`` counts the result() calls a flush answered: a
     planned job no node claims is trained (``jobs_run``) but never served.
-    ``batch_bytes_h2d`` is the size of the batches, labels, masks and
-    active flags each group copies to the device."""
+    ``batch_bytes_h2d`` is what the engine copies to the device for
+    training: each client's shard once (rows padded to whole batches,
+    labels as int32), then per group its indices, masks and active
+    flags."""
+    clients = _fresh(small_clients)
     params = task.init_params(0)
     engine = BatchedEngine(task)
-    for i, c in enumerate(small_clients):
+    for i, c in enumerate(clients):
         engine.register_client(str(i), c)
     # clients 0 and 1 (25 and 40 samples) both take 2 steps: one group
     engine.plan_cohort(1, ["0", "1"], params, batch_size=20, epochs=1,
                        seed=11)
-    engine.result("0", 1, params, small_clients[0], batch_size=20, epochs=1,
+    engine.result("0", 1, params, clients[0], batch_size=20, epochs=1,
                   seed=11)
     assert (engine.flushes, engine.jobs_run, engine.jobs_served) == (1, 2, 1)
-    c = small_clients[0]
+    row = clients[0].x[0].nbytes + 4            # an image and its label
     T, S, B = 2, 2, 20
-    want = T * S * B * (c.x[0].nbytes + c.y[:1].nbytes + 4) + T * S
+    want = 2 * 40 * row + T * S * B * (4 + 4) + T * S
     assert engine.batch_bytes_h2d == want
-    # a job never planned is trained alone and served: one more group
-    engine.result("2", 1, params, small_clients[2], batch_size=20, epochs=1,
+    assert engine.shard_uploads == 2
+    # a job never planned is trained alone and served: one more group,
+    # and the 15-sample client's shard padded to one batch of 20
+    engine.result("2", 1, params, clients[2], batch_size=20, epochs=1,
                   seed=11)
     assert (engine.flushes, engine.jobs_run, engine.jobs_served) == (2, 3, 2)
-    assert engine.batch_bytes_h2d == want + 1 * 1 * B * (
-        c.x[0].nbytes + c.y[:1].nbytes + 4) + 1
+    assert engine.batch_bytes_h2d == want + 20 * row + 1 * 1 * B * 8 + 1
+    assert engine.shard_uploads == 3
     # client 1's planned job is never claimed: run, not served
     assert engine.jobs_served < engine.jobs_run
+
+
+def _spy_batches(engine):
+    """Record ``(x, y, mask, active)`` of every step as the step program
+    receives them (a scan's ``(T, S, …)`` inputs split into its steps)."""
+    steps = []
+    step, scan = engine._step, engine._scan
+
+    def spy_step(buf, state, *batch):
+        steps.append(batch)
+        return step(buf, state, *batch)
+
+    def spy_scan(buf, state, *batches):
+        steps.extend(zip(*batches))
+        return scan(buf, state, *batches)
+
+    engine._step, engine._scan = spy_step, spy_scan
+    return steps
+
+
+@pytest.mark.parametrize("sizes", [(25, 40), (25,)],
+                         ids=["step-form", "scan-form"])
+def test_device_gathered_batches_match_padded_batches(task, sizes):
+    """The batches the engine gathers on the device are, bit for bit, the
+    ones ``_padded_batches`` builds on the host for the same seed: ragged
+    shards (a short last batch), two epochs, and two clients of different
+    lengths in one step-count group (vmapped together, per-step form) or
+    one client alone (scan form)."""
+    rng = np.random.default_rng(4)
+    clients = [ClientDataset(rng.normal(size=(n, 32, 32, 3))
+                             .astype(np.float32), rng.integers(0, 10, n))
+               for n in sizes]
+    params = task.init_params(0)
+    engine = BatchedEngine(task)
+    steps = _spy_batches(engine)
+    for i, c in enumerate(clients):
+        engine.submit(str(i), 1, params, c, batch_size=20, epochs=2,
+                      seed=30 + i)
+    engine.result("0", 1, params, clients[0], batch_size=20, epochs=2,
+                  seed=30)
+    assert engine.flushes == 1 and len(steps) == 4
+    for s, c in enumerate(clients):
+        want = task._padded_batches(c, 20, seed=30 + s, epochs=2)
+        assert len(want) == len(steps)
+        for (x, y, m, a), (wx, wy, wm) in zip(steps, want):
+            np.testing.assert_array_equal(np.asarray(x[s]), wx)
+            np.testing.assert_array_equal(np.asarray(y[s]), wy)
+            np.testing.assert_array_equal(np.asarray(m[s]), wm)
+            assert bool(a[s])
+
+
+def test_shard_uploaded_once_per_task(task, small_clients):
+    """A shard goes to the device once: not again at a second flush, nor
+    from a second engine on the same task (sessions build one each); a
+    client whose shape changed is uploaded again."""
+    c = _fresh(small_clients[:1])[0]
+    params = task.init_params(0)
+    first = BatchedEngine(task)
+    for tag in (1, 2):
+        first.result("0", tag, params, c, batch_size=20, epochs=1, seed=tag)
+    assert (first.flushes, first.shard_uploads) == (2, 1)
+    second = BatchedEngine(task)
+    second.result("0", 1, params, c, batch_size=20, epochs=1, seed=5)
+    assert (second.flushes, second.shard_uploads) == (1, 0)
+    # the bytes of a flush served from resident shards: its plan alone
+    assert second.batch_bytes_h2d == 2 * 20 * 8 + 2
+    c.x, c.y = np.concatenate([c.x, c.x[:5]]), np.concatenate([c.y, c.y[:5]])
+    got = second.result("0", 2, params, c, batch_size=20, epochs=1, seed=6)
+    assert second.shard_uploads == 1
+    want = task.local_train(params, c, batch_size=20, epochs=1, seed=6)
+    assert _max_err(want, got.tree) < 5e-4
+
+
+def test_cohort_matches_sequential_mf():
+    """The gather is blind to the input's dtype and trailing shape: an
+    mf-family cohort (int32 ``(user, item)`` pairs, float ratings) of
+    ragged shards matches the sequential engine."""
+    from repro.data.synthetic import make_mf_task
+    from repro.models.tasks import mf_task
+
+    task = mf_task()
+    data = make_mf_task(3, n_items=1000, seed=1)
+    clients = [ClientDataset(c.x[:n], c.y[:n])
+               for c, n in zip(data.clients, (40, 33, 7))]
+    params = task.init_params(0)
+    engine = BatchedEngine(task)
+    for i, c in enumerate(clients):
+        engine.submit(str(i), 1, params, c, batch_size=20, epochs=2, seed=i)
+    for i, c in enumerate(clients):
+        got = engine.result(str(i), 1, params, c, batch_size=20, epochs=2,
+                            seed=i)
+        want = task.local_train(params, c, batch_size=20, epochs=2, seed=i)
+        assert _max_err(want, got.tree) < 1e-5
+    # (40, 33) take two steps an epoch, 7 takes one: two groups
+    assert (engine.jobs_run, engine.flushes) == (3, 2)
 
 
 def test_cohort_matches_sequential_bf16(small_clients):
