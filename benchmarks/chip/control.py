@@ -90,9 +90,11 @@ def pad_witness(cell, recorder, extra: int = 16) -> list:
     mean with that short batch padded by copies of its first row, each
     weighted as a real row of the batch mean: what the program's padding
     gives."""
+    import jax.numpy as jnp
     import numpy as np
 
     from repro.data.loader import ClientDataset
+    from repro.engine.flat import FlatModel
 
     x, y = cell.test
     x, y = np.concatenate([x, x[:extra]]), np.concatenate([y, y[:extra]])
@@ -112,7 +114,8 @@ def pad_witness(cell, recorder, extra: int = 16) -> list:
         short, first = ref(n - r, n) * r, ref(n - r, n - r + 1)
         padded = (plain * n - short + r / EVAL_BATCH *
                   (short + (EVAL_BATCH - r) * first)) / n
-        got = cell.task.evaluate_many([model], ClientDataset(x, y))[0]
+        on_chip = FlatModel(jnp.asarray(model.buffer), model.spec)
+        got = cell.task.evaluate_many([on_chip], ClientDataset(x, y))[0]
         out.append({"program": got["loss"], "plain": plain,
                     "padded": padded})
     return out
@@ -150,7 +153,7 @@ def main() -> int:
 
     m = harness.manifest()
     w = harness.workload(m, args.workload)
-    find_chips(w["chips"])
+    devices = find_chips(w["chips"])
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
@@ -161,16 +164,17 @@ def main() -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         cell = harness.build_cell(w["name"], config, traffic, seed,
-                                  task=task)
+                                  task=task, devices=devices)
         if task is None:
             harness.warm_shapes(cell)
             task = cell.task
-        recorder = harness.Recorder(seed)
+        recorder = harness.Recorder(seed, harness.sample_counts(config))
         win = harness.run_window(cell, args.seconds, recorder, compiles,
                                  log=lambda *a: print(*a, file=sys.stderr))
         line = {"workload": w["name"], "seed": seed,
                 "sessions": win.attempted, "failed": win.failed,
-                "rounds": win.rounds, "window_compiles": win.compiles}
+                "rounds": win.rounds, "window_compiles": win.compiles,
+                "window_s": win.seconds}
         line.update(read_seed(cell, recorder))
         line["seconds"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)

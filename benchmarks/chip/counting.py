@@ -2,8 +2,10 @@
 
 ``train_flops_per_sample`` counts the multiply-adds of one forward pass
 (2 FLOPs each) and takes the backward pass as twice the forward, the
-usual 3x for training. Element-wise work (bias, ReLU, pooling, softmax)
-is left out. ``aggregation_bytes`` is the HBM traffic the aggregation
+usual 3x for training. A family's forward count lives in a file of its
+own, ``counts/<family>.py``, whose ``forward_macs(model)`` this module
+finds by the family's name; an unknown family is an error that names the
+missing file. ``aggregation_bytes`` is the HBM traffic the aggregation
 algorithm needs for one call, at the unpadded width N, as
 ``repro.roofline.aggregation_roofline`` models the one-pass kernel.
 """
@@ -14,32 +16,19 @@ import json
 import math
 import os
 
-PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "peaks.json")
+HERE = os.path.dirname(os.path.abspath(__file__))
+PEAKS = os.path.join(HERE, "peaks.json")
 
 
-def _cnn_macs(m: dict) -> int:
-    h, w, c = m["image"]
-    k = m["kernel"]
-    macs = 0
-    for c_out in m["channels"]:
-        macs += h * w * k * k * c * c_out          # "same" convolution
-        h, w, c = h // m["pool"], w // m["pool"], c_out
-    width = h * w * c
-    for d in list(m["hidden"]) + [m["classes"]]:
-        macs += width * d
-        width = d
-    return macs
+def forward_flops_per_sample(model: dict, base: str = HERE) -> int:
+    from harness import load_module
+
+    return 2 * load_module("counts", model["family"], base).forward_macs(
+        model)
 
 
-_MACS = {"cnn": _cnn_macs}
-
-
-def forward_flops_per_sample(model: dict) -> int:
-    return 2 * _MACS[model["family"]](model)
-
-
-def train_flops_per_sample(model: dict) -> int:
-    return 3 * forward_flops_per_sample(model)
+def train_flops_per_sample(model: dict, base: str = HERE) -> int:
+    return 3 * forward_flops_per_sample(model, base)
 
 
 def aggregation_bytes(n: int, p: int, *, itemsize: int = 4,

@@ -18,11 +18,17 @@ metric lives in a file of its own, found by the name in
 * ``datasets/<kind>.py``, ``profiles/<kind>.py``, ``sessions/<kind>.py``
   — the generators and session builders the data files name;
 * ``reference/<family>.py`` — the plain reference of a model family;
+* ``counts/<family>.py`` — the operation count of a model family;
 * ``layers/<metric>.py`` — one reader per per-layer metric.
+
+A cell's ``chips`` decide its engine (:func:`engine_factory`): one chip
+runs the batched engine the sessions pick themselves, four run every
+session, and the warm-up, on a ``MeshEngine`` over the cell's devices.
 """
 
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
 import math
@@ -40,8 +46,9 @@ for _p in (os.path.join(REPO, "src"), HERE):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-SAMPLES = 8            # sampled answers of each kind checked per run
-EVAL_SAMPLES = 4       # evaluated models checked per run
+# sampled answers of each kind checked per run, unless the config's
+# ``check`` key sets them: training jobs, aggregations, evaluated models
+SAMPLES = {"train": 8, "agg": 8, "eval": 4}
 
 
 # ----------------------------------------------------------------- discovery
@@ -129,6 +136,7 @@ class Cell:
     reference: object = None
     shards: List[tuple] = field(default_factory=list)
     test: tuple = ()
+    new_engine: Callable = None           # a fresh engine for a session
 
 
 def train_config(config: dict, seed: int = 0):
@@ -159,10 +167,47 @@ def build_profile(traffic: dict, seed: int, base: str = HERE):
                            for w in g["windows"]))
 
 
+def engine_factory(task, devices: Optional[list] = None) -> Callable:
+    """``new_engine()`` for a cell on ``devices``. On one device (or
+    ``None``), the engine a session picks for the task itself. On more, a
+    ``MeshEngine`` whose mesh is exactly those devices: the program's
+    ``"sharded"`` engine where they are every local device, else one the
+    harness builds over them."""
+    from repro.engine.cohort import MeshEngine, make_engine
+
+    if devices is None or len(devices) == 1:
+        return lambda: make_engine(None, task)
+    import jax
+
+    if list(devices) == list(jax.devices()):
+        return lambda: make_engine("sharded", task)
+    from jax.sharding import AxisType, Mesh
+
+    mesh = Mesh(np.array(devices).reshape(1, len(devices)),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    return lambda: MeshEngine(task, mesh)
+
+
+@contextlib.contextmanager
+def given_engine(engine):
+    """Inside this block a session of ``repro.sim.runner`` takes
+    ``engine``: the sessions pick their engine by kind as they are built
+    (``make_engine``) and take no engine object."""
+    import repro.sim.runner as runner
+
+    real = runner.make_engine
+    runner.make_engine = lambda kind, task: engine
+    try:
+        yield engine
+    finally:
+        runner.make_engine = real
+
+
 def build_cell(name: str, config: dict, traffic: dict, seed: int,
-               base: str = HERE, task=None) -> Cell:
+               base: str = HERE, task=None, devices=None) -> Cell:
     """The cell's inputs from ``seed``; ``task`` reuses a task built from
-    the same config (and its compiled programs)."""
+    the same config (and its compiled programs); ``devices`` are the
+    cell's chips (:func:`engine_factory`)."""
     from repro.data.loader import ClientDataset, FederatedData
 
     cell = Cell(name, config, traffic, seed)
@@ -175,6 +220,7 @@ def build_cell(name: str, config: dict, traffic: dict, seed: int,
         test=ClientDataset(*cell.test), task=ds["kind"])
     cell.profile = build_profile(traffic, traffic["protocol_seed"], base)
     cell.task = task if task is not None else build_task(config)
+    cell.new_engine = engine_factory(cell.task, devices)
     cell.builder = load_module("sessions", traffic["session"], base).build
     cell.reference = load_module("reference", config["model"]["family"],
                                  base)
@@ -185,12 +231,14 @@ def new_session(cell: Cell, index: int):
     """Session ``index`` of the traffic's pool (``-1`` is the warm-up
     session). Its protocol (sampling, churn, network) comes from the
     traffic's ``protocol_seed``, so every run's pool does the same work;
-    its weights and batch order come from the run's seed."""
+    its weights and batch order come from the run's seed. It runs on a
+    fresh engine of the cell's (:func:`engine_factory`)."""
     return cell.builder(
         task=cell.task, data=cell.data, profile=cell.profile,
         traffic=cell.traffic,
         tcfg=train_config(cell.config, session_seed(cell.seed, index)),
-        seed=session_seed(cell.traffic["protocol_seed"], index))
+        seed=session_seed(cell.traffic["protocol_seed"], index),
+        engine=cell.new_engine())
 
 
 # ------------------------------------------------------------------- warm-up
@@ -201,13 +249,12 @@ def warm_shapes(cell: Cell, log=None) -> int:
     the engine's public calls: each cohort group (``S`` jobs of the same
     step count ``T``, for every ``T`` in the shards and ``S`` up to the
     traffic's ``warm.max_group``), each aggregation of ``1..max_agg``
-    models and each evaluation sweep of ``1..max_eval`` models. Returns
-    the number of engine calls made."""
-    from repro.engine.cohort import make_engine
-
+    models and each evaluation sweep of ``1..max_eval`` models, on an
+    engine of the cell's, so its sessions find every program compiled.
+    Returns the number of engine calls made."""
     warm = cell.traffic["warm"]
     task, tcfg = cell.task, train_config(cell.config)
-    engine = make_engine(None, task)
+    engine = cell.new_engine()
     params = task.init_params(0)
     bs, epochs = tcfg.batch_size, cell.traffic["local_epochs"]
     by_steps: Dict[int, list] = {}
@@ -237,6 +284,14 @@ def warm_shapes(cell: Cell, log=None) -> int:
     for m in range(1, warm["max_eval"] + 1):
         engine.evaluate_models([out] * m, cell.data.test)
         calls += 1
+    # a result asked for with an equal model under another object (two
+    # aggregators of one round): the engine matches the two by value
+    twin, tag, i = engine.aggregate([out]), tag + 1, by_steps[steps][0]
+    engine.submit(f"w{i}", tag, out, cell.data.clients[i], batch_size=bs,
+                  epochs=epochs, seed=tag)
+    engine.result(f"w{i}", tag, twin, cell.data.clients[i], batch_size=bs,
+                  epochs=epochs, seed=tag)
+    calls += 2
     import jax
 
     jax.block_until_ready(out.buffer if hasattr(out, "buffer") else out)
@@ -246,20 +301,92 @@ def warm_shapes(cell: Cell, log=None) -> int:
 # -------------------------------------------------------- the timed sessions
 
 
+class HostModel:
+    """A model the program produced, copied to host memory: its flat
+    float32 buffer as a numpy array and the spec that names its leaves."""
+
+    def __init__(self, buffer: np.ndarray, spec):
+        self.buffer, self.spec = buffer, spec
+
+    def tree(self) -> dict:
+        out = []
+        for off, size, shape, dt in zip(self.spec.offsets, self.spec.sizes,
+                                        self.spec.shapes, self.spec.dtypes):
+            x = self.buffer[off:off + size].reshape(shape)
+            if np.issubdtype(dt, np.integer):
+                x = np.rint(x)
+            out.append(x.astype(dt))
+        return self.spec.treedef.unflatten(out)
+
+
+def _device_arrays(x) -> list:
+    """The device arrays of a sampled answer: of its models (a
+    ``FlatModel`` is a leaf of the tree) and of its other leaves."""
+    import jax
+
+    return [a for a in (getattr(v, "buffer", v) for v in jax.tree.leaves(x))
+            if isinstance(a, jax.Array)]
+
+
+def _to_host(x):
+    """``x`` with every model a :class:`HostModel` and every other device
+    array a numpy array."""
+    import jax
+
+    from repro.engine.flat import FlatModel
+
+    def one(v):
+        if isinstance(v, FlatModel):
+            return HostModel(np.asarray(v.buffer), v.spec)
+        return np.asarray(v) if isinstance(v, jax.Array) else v
+
+    return jax.tree.map(one, x)
+
+
+class Sample:
+    """A sampled answer on its way to host memory. When kept, the copy of
+    its device arrays back to the host starts (it runs once the device has
+    produced them); :meth:`settle` swaps them for host arrays once they
+    are there, and from then on the sample holds no device array."""
+
+    def __init__(self, item: tuple):
+        self.item = item
+        self.arrays = _device_arrays(item)
+        for a in self.arrays:
+            a.copy_to_host_async()
+
+    @property
+    def ready(self) -> bool:
+        return all(a.is_ready() for a in self.arrays)
+
+    def settle(self) -> None:
+        if self.arrays:
+            self.item, self.arrays = _to_host(self.item), []
+
+
 class Reservoir:
-    """A uniform sample of ``k`` of the offers, drawn from ``rng``."""
+    """A uniform sample of ``k`` of the offers, drawn from ``rng``; an
+    offer that is kept becomes a :class:`Sample` (``keep``)."""
 
-    def __init__(self, k: int, rng):
-        self.k, self.rng, self.seen, self.items = k, rng, 0, []
+    def __init__(self, k: int, rng, keep: Callable):
+        self.k, self.rng, self.seen, self.kept = k, rng, 0, []
+        self.keep = keep
 
-    def offer(self, item) -> None:
+    def offer(self, item):
+        """What the reservoir keeps of ``item``, or None."""
         self.seen += 1
-        if len(self.items) < self.k:
-            self.items.append(item)
-            return
+        if len(self.kept) < self.k:
+            self.kept.append(self.keep(item))
+            return self.kept[-1]
         j = int(self.rng.integers(0, self.seen))
         if j < self.k:
-            self.items[j] = item
+            self.kept[j] = self.keep(item)
+            return self.kept[j]
+        return None
+
+    @property
+    def items(self) -> list:
+        return [getattr(s, "item", s) for s in self.kept]
 
 
 def _ready(models) -> None:
@@ -282,24 +409,61 @@ class _Stamped(list):
         super().append(item)
 
 
+def sample_counts(config: dict) -> Dict[str, int]:
+    """Sampled answers of each kind a run checks: the config's ``check``
+    key over :data:`SAMPLES`."""
+    return {**SAMPLES, **config.get("check", {})}
+
+
 class Recorder:
     """Wraps the public calls of each session's engine: host spans for the
-    trace, sampled answers for the check, and counts."""
+    trace, sampled answers for the check (copied to host memory: see
+    :class:`Sample`), and counts. With :attr:`note_programs` it also notes
+    the argument shapes of the cohort step programs each call runs, to
+    compile their text for the trace's op scopes."""
 
-    def __init__(self, seed: int):
+    def __init__(self, seed: int, samples: Dict[str, int] = SAMPLES):
         rng = np.random.default_rng([seed, 99])
-        self.train = Reservoir(SAMPLES, rng)
-        self.agg = Reservoir(SAMPLES, rng)
-        self.evals = Reservoir(EVAL_SAMPLES, rng)
-        self.longest = None               # the train job with most samples
+        self.pending: List[Sample] = []
+        self.train = Reservoir(samples["train"], rng, self._keep)
+        self.agg = Reservoir(samples["agg"], rng, self._keep)
+        self.evals = Reservoir(samples["eval"], rng, self._keep)
+        self.longest = None               # Sample: the job with most
+        self.longest_n = 0                # samples, and their number
         self.samples_trained = 0
         self.agg_sizes: List[int] = []
         # host seconds inside each public call, for the run's log
         self.host_s = {"result": 0.0, "aggregate": 0.0, "evaluate": 0.0}
+        self.copy_s = 0.0                 # host seconds copying samples
         # in the traced session, the aggregation and evaluation spans
         # start once their inputs are on the device and end once their
         # answer is, so that they hold that layer's work and no other
         self.sync = False
+        self.note_programs = False
+        self.calls: Dict[tuple, tuple] = {}   # (program, shapes) -> args
+
+    def _keep(self, item) -> Sample:
+        t0 = time.perf_counter()
+        s = Sample(item)
+        if s.arrays:
+            self.pending.append(s)
+        self.copy_s += time.perf_counter() - t0
+        return s
+
+    def settle(self, wait: bool = False) -> None:
+        """Swap the kept samples whose copies are done (``wait``: all)
+        for host arrays."""
+        if not self.pending:
+            return
+        t0 = time.perf_counter()
+        left = []
+        for s in self.pending:
+            if wait or s.ready:
+                s.settle()
+            else:
+                left.append(s)
+        self.pending = left
+        self.copy_s += time.perf_counter() - t0
 
     def attach(self, engine) -> None:
         from jax.profiler import TraceAnnotation
@@ -309,6 +473,7 @@ class Recorder:
 
         def traced_result(node_id, tag, params, client, *, batch_size,
                           epochs, seed, lr_scale=1.0):
+            self.settle()
             t0 = time.perf_counter()
             with TraceAnnotation("bench.result"):
                 out = result(node_id, tag, params, client,
@@ -318,13 +483,13 @@ class Recorder:
             n = len(client) * epochs
             self.samples_trained += n
             job = (params, client, batch_size, epochs, seed, lr_scale, out)
-            self.train.offer(job)
-            if self.longest is None or n > len(self.longest[1]) * \
-                    self.longest[3]:
-                self.longest = job
+            kept = self.train.offer(job)
+            if self.longest is None or n > self.longest_n:
+                self.longest, self.longest_n = kept or self._keep(job), n
             return out
 
         def traced_aggregate(models, weights=None):
+            self.settle()
             if self.sync:
                 _ready(models)
             t0 = time.perf_counter()
@@ -338,6 +503,7 @@ class Recorder:
             return out
 
         def traced_evaluate(models, test):
+            self.settle()
             if self.sync:
                 _ready(models)
             t0 = time.perf_counter()
@@ -351,6 +517,43 @@ class Recorder:
         engine.result = traced_result
         engine.aggregate = traced_aggregate
         engine.evaluate_models = traced_evaluate
+        if self.note_programs:
+            self._watch_programs(engine)
+
+    def _watch_programs(self, engine) -> None:
+        """Note the argument shapes (and, across chips, shardings) of each
+        cohort program shape the engine runs."""
+        import jax
+
+        def abstract(a):
+            sharding = getattr(a, "sharding", None)
+            if sharding is None or len(sharding.device_set) < 2:
+                return jax.ShapeDtypeStruct(a.shape, a.dtype)
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+        for attr in ("_step", "_scan"):
+            fn = getattr(engine, attr, None)
+            if fn is None:
+                continue
+
+            def watch(*args, _fn=fn):
+                key = (_fn, args[0].shape, args[2].shape)
+                if key not in self.calls:
+                    self.calls[key] = jax.tree.map(abstract, args)
+                return _fn(*args)
+
+            setattr(engine, attr, watch)
+
+    def program_texts(self, log=None) -> List[str]:
+        """The compiled text of every cohort program shape noted."""
+        out = []
+        for (fn, _, _), args in self.calls.items():
+            try:
+                out.append(fn.lower(*args).compile().as_text())
+            except Exception as e:            # the text is optional
+                if log is not None:
+                    log(f"no compiled text: {e!r}")
+        return out
 
 
 @dataclass
@@ -359,6 +562,10 @@ class SessionStats:
     events: int = 0
     flushes: int = 0
     jobs: int = 0
+    # the engine's counters (None where the engine has no such counter)
+    jobs_served: Optional[int] = None
+    batch_bytes_h2d: Optional[int] = None
+    shard_uploads: Optional[int] = None
     wall_s: float = 0.0
     round_walls: List[float] = field(default_factory=list)
     agg_sizes: List[int] = field(default_factory=list)
@@ -397,8 +604,12 @@ def run_session(cell: Cell, index: int, recorder: Recorder,
     stats.host_s = {k: v - host0[k] for k, v in recorder.host_s.items()}
     stats.rounds = len(result.round_times)
     stats.events = session.sim.events_processed
-    stats.flushes = getattr(session.engine, "flushes", 0)
-    stats.jobs = getattr(session.engine, "jobs_run", 0)
+    e = session.engine
+    stats.flushes = getattr(e, "flushes", 0)
+    stats.jobs = getattr(e, "jobs_run", 0)
+    stats.jobs_served = getattr(e, "jobs_served", None)
+    stats.batch_bytes_h2d = getattr(e, "batch_bytes_h2d", None)
+    stats.shard_uploads = getattr(e, "shard_uploads", None)
     stats.agg_sizes = recorder.agg_sizes[n_agg:]
     stats.ok = finite_history(result)
     if len(stats.round_walls) != len(result.round_times):
@@ -437,9 +648,12 @@ class Window:
     compiles: int = 0
     compiled: List[str] = field(default_factory=list)
     trace: object = None                  # trace_reduce.Reduction
+    program: object = None                # program_trace.ProgramTrace
     traced: Optional[SessionStats] = None
     samples_trained: int = 0
     start: float = 0.0
+    texts_s: float = 0.0                  # compiling the step programs'
+                                          # text for the op scopes
 
     @property
     def rounds(self) -> int:
@@ -463,11 +677,13 @@ def run_window(cell: Cell, seconds: float, recorder: Recorder,
     whole passes: the same work for every seed. With ``trace_dir`` the
     second session runs under the profiler, inside a ``bench.window``
     span, with the recorder's spans synchronised
-    (:attr:`Recorder.sync`)."""
+    (:attr:`Recorder.sync`); once the window has closed the trace is
+    reduced twice: the benchmark's spans (:mod:`trace_reduce`) and the
+    program's own (:mod:`program_trace`, op scopes from the compiled text
+    of the step programs that session ran). Sampled answers still on their
+    way to the host are waited for once the clock has stopped."""
     import jax
     from jax.profiler import ProfileOptions, TraceAnnotation
-
-    import trace_reduce
 
     options = ProfileOptions()
     options.host_tracer_level = 1         # the benchmark's spans, no more
@@ -484,7 +700,7 @@ def run_window(cell: Cell, seconds: float, recorder: Recorder,
         if tracing:
             jax.profiler.start_trace(trace_dir, profiler_options=options)
         win.attempted += 1
-        recorder.sync = tracing
+        recorder.sync = recorder.note_programs = tracing
         try:
             with TraceAnnotation("bench.window" if tracing else
                                  "bench.session"):
@@ -497,7 +713,7 @@ def run_window(cell: Cell, seconds: float, recorder: Recorder,
             win.failed += 1
             stats = None
         finally:
-            recorder.sync = False
+            recorder.sync = recorder.note_programs = False
             if tracing:
                 jax.profiler.stop_trace()
         if stats is not None:
@@ -508,13 +724,32 @@ def run_window(cell: Cell, seconds: float, recorder: Recorder,
                 win.traced = stats
         i += 1
     win.seconds = time.perf_counter() - t0
+    recorder.settle(wait=True)            # outside the timed window
     win.compiles = compiles.count - c0
     win.compiled = compiles.names[c0:]
     win.samples_trained = recorder.samples_trained
     if trace_dir is not None:
         path = find_xplane(trace_dir)
-        win.trace = trace_reduce.reduce_file(path) if path else None
+        if path:
+            reduce_trace(win, path, recorder, log)
     return win
+
+
+def reduce_trace(win: Window, path: str, recorder: Recorder,
+                 log=print) -> None:
+    """Reduce the traced session's ``.xplane.pb`` onto ``win``."""
+    from jax.profiler import ProfileData
+
+    import program_trace
+    import trace_reduce
+
+    data = ProfileData.from_file(path)
+    # ``data.planes`` can be iterated once: each reduction asks anew
+    win.trace = trace_reduce.reduce_planes(data.planes)
+    t0 = time.perf_counter()
+    scopes = program_trace.hlo_op_scopes(recorder.program_texts(log))
+    win.texts_s = time.perf_counter() - t0
+    win.program = program_trace.reduce_program(data.planes, scopes)
 
 
 def find_xplane(root: str) -> Optional[str]:
@@ -534,7 +769,8 @@ def leaves(params) -> Dict[str, np.ndarray]:
 
     from repro.engine.flat import as_tree
 
-    tree = jax.device_get(as_tree(params))
+    tree = params.tree() if isinstance(params, HostModel) else \
+        jax.device_get(as_tree(params))
     return {k: np.asarray(v, np.float32) for k, v in tree.items()}
 
 
@@ -660,8 +896,8 @@ def sampled_jobs(recorder: Recorder) -> list:
     """The sampled training jobs, with the longest job of the window."""
     jobs = list(recorder.train.items)
     if recorder.longest is not None and all(
-            j is not recorder.longest for j in jobs):
-        jobs.append(recorder.longest)
+            s is not recorder.longest for s in recorder.train.kept):
+        jobs.append(recorder.longest.item)
     return jobs
 
 
@@ -732,5 +968,7 @@ def check(cell: Cell, recorder: Recorder) -> Dict[str, dict]:
 
 
 def correct(win: Window, checks: Dict[str, dict]) -> bool:
-    return bool(win.failed == 0 and win.rounds > 0 and all(
+    """No session failed, some round completed, and every number compared
+    lies within its limit; a run that compared nothing is not correct."""
+    return bool(win.failed == 0 and win.rounds > 0 and checks and all(
         c["value"] <= c["limit"] for c in checks.values()))

@@ -337,6 +337,22 @@ READERS = {f.__name__: f for f in (
     loop_self_share, train_glue_share, jobs_served_share, h2d_mb_per_round)}
 
 
+def session_counters(stats) -> Counters:
+    """The counters of a session the harness ran (``SessionStats``)."""
+    return Counters(flushes=stats.flushes, jobs_run=stats.jobs,
+                    rounds=stats.rounds, jobs_served=stats.jobs_served,
+                    batch_bytes_h2d=stats.batch_bytes_h2d)
+
+
+def read(name: str, window) -> Optional[float]:
+    """Number ``name`` of a window's traced session (the harness's
+    ``Window``): its program reduction and its session's counters; None
+    where the window has no trace."""
+    if window.program is None or window.traced is None:
+        return None
+    return READERS[name](window.program, session_counters(window.traced))
+
+
 def numbers(t: ProgramTrace, c: Counters) -> Dict[str, Optional[float]]:
     """Each of the seven numbers, ``None`` where the trace or the counters
     hold nothing to read."""

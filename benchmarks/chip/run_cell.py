@@ -98,7 +98,8 @@ def main() -> int:
     peaks = counting.peaks(devices[0].device_kind)
     config = harness.load_json("configs", w["config"])
     traffic = harness.load_json("traffic", w["traffic"])
-    cell = harness.build_cell(w["name"], config, traffic, args.seed)
+    cell = harness.build_cell(w["name"], config, traffic, args.seed,
+                              devices=devices)
     t = time.perf_counter()
     calls = harness.warm_shapes(cell, log=log)
     log(f"warm: {calls} engine calls in {time.perf_counter() - t:.3f} s")
@@ -109,7 +110,7 @@ def main() -> int:
     log(f"setup_s={setup_s} compiles={compiles.count} "
         f"({compiles.seconds:.3f} s)")
 
-    recorder = harness.Recorder(args.seed)
+    recorder = harness.Recorder(args.seed, harness.sample_counts(config))
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if args.trace \
         else None
     try:
@@ -120,7 +121,10 @@ def main() -> int:
             shutil.rmtree(trace_dir, ignore_errors=True)
     log(f"window: {win.seconds:.3f} s, {win.attempted} sessions, "
         f"{win.rounds} rounds, {win.compiles} compiles, "
-        f"{win.failed} failed")
+        f"{win.failed} failed; {recorder.copy_s:.3f} s copying samples "
+        f"to the host")
+    if args.trace:
+        log(f"step programs' text for the op scopes: {win.texts_s:.3f} s")
     if win.compiled:
         log(f"compiled in the window: {sorted(set(win.compiled))}")
     for s in win.sessions:
@@ -152,9 +156,11 @@ def main() -> int:
         if t is not None:
             device["busy_s"] = t.busy_ns / 1e9
             device["window_s"] = t.window_ns / 1e9
+            # gaps labelled by the innermost span, the program's or ours
+            gaps = (win.program or t).gaps
             line["breakdown"] = {
                 "device_ops": t.top_ops(10),
-                "idle_gaps": [[n, s / 1e9] for n, s in t.gaps]}
+                "idle_gaps": [[n, s / 1e9] for n, s in gaps]}
     else:
         metrics = end_to_end(win, setup_s) if win.rounds else {}
     line["metrics"] = metrics

@@ -1,10 +1,11 @@
 """A MoDeST/Plexus session (``repro.sim.runner.ModestSession``) on the
-engine that the session picks for the task (no ``engine=``)."""
+engine the harness gives it."""
 
 from __future__ import annotations
 
 
-def build(*, task, data, profile, traffic: dict, tcfg, seed: int):
+def build(*, task, data, profile, traffic: dict, tcfg, seed: int, engine):
+    from harness import given_engine
     from repro.config import ModestConfig
     from repro.sim.runner import ModestSession
 
@@ -14,7 +15,8 @@ def build(*, task, data, profile, traffic: dict, tcfg, seed: int):
                         success_fraction=traffic["success_fraction"],
                         ping_timeout=traffic["ping_timeout"],
                         local_steps=traffic["local_epochs"], seed=seed)
-    return ModestSession(n_nodes=n, mcfg=mcfg, tcfg=tcfg, task=task,
-                         data=data, seed=seed, profile=profile,
-                         churn_from_profile=traffic["churn"],
-                         eval_every_rounds=traffic["eval_every_rounds"])
+    with given_engine(engine):
+        return ModestSession(n_nodes=n, mcfg=mcfg, tcfg=tcfg, task=task,
+                             data=data, seed=seed, profile=profile,
+                             churn_from_profile=traffic["churn"],
+                             eval_every_rounds=traffic["eval_every_rounds"])

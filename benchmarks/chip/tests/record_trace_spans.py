@@ -74,7 +74,7 @@ def main() -> int:
     stats, recorder, data, _ = traced_session(cell, 0, 3)
     scopes = program_trace.hlo_op_scopes(recorder.program_texts())
     doc = {"planes": slim(data.planes, scopes),
-           "counters": vars(recorder.counters(stats.rounds)),
+           "counters": vars(program_trace.session_counters(stats)),
            "agg_sizes": stats.agg_sizes, "n_params": cell.task.flat_spec.n}
     os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
     with gzip.open(out, "wt") as f:

@@ -30,6 +30,11 @@ def test_config_files_state_their_counts(name):
     assert config["params"] == harness.build_task(config).flat_spec.n
 
 
+def test_an_unknown_family_is_an_error_naming_its_file():
+    with pytest.raises(KeyError, match=r"counts/no-such-family\.py"):
+        counting.train_flops_per_sample({"family": "no-such-family"})
+
+
 @pytest.mark.parametrize("p", [1, 2, 8, 10])
 def test_aggregation_bytes(p):
     n = 136_672

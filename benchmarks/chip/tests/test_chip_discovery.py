@@ -27,6 +27,7 @@ def test_every_name_resolves():
         harness.load_module("profiles", traffic["profile"]["kind"])
         harness.load_module("datasets", config["dataset"]["kind"])
         harness.load_module("reference", config["model"]["family"])
+        harness.load_module("counts", config["model"]["family"])
         assert set(harness.readers(M, w["name"])) == {
             x["name"] for x in M["per_layer"]
             if w["name"] in x.get("workloads", [w["name"]])}
@@ -58,14 +59,49 @@ def test_manifest_shape():
                                "program_counter", "host_clock")
     for w in M["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    assert four_chip_cells_allowed(M)
     assert len(json.dumps(M)) < 64 * 1024
 
 
+def four_chip_cells_allowed(m: dict) -> bool:
+    """At most half the cells, rounded down, ask for four chips; one
+    always may."""
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    return four <= max(1, len(m["workloads"]) // 2)
+
+
+def test_four_chip_cells_are_at_most_half():
+    one = {"chips": 1}
+    four = {"chips": 4}
+    assert four_chip_cells_allowed({"workloads": [four]})
+    assert four_chip_cells_allowed({"workloads": [four, one, one]})
+    assert not four_chip_cells_allowed({"workloads": [four, four, one]})
+    assert four_chip_cells_allowed({"workloads": [four, four, one, one]})
+
+
+STUB_REFERENCE = """
+def train(params, x, y, **kw):
+    return dict(params)
+
+
+def evaluate(params, x, y, **kw):
+    return {"loss": 1.0}
+"""
+STUB_COUNTS = """
+def forward_macs(model):
+    return model["width"] * model["depth"]
+"""
+
+
 def test_a_new_cell_is_files_and_entries(tmp_path):
-    """Copy the benchmark, add a configuration, a traffic mix and a
-    per-layer metric as new files, and list them in the manifest: the
-    harness finds them, and no file that was there changes."""
+    """Copy the benchmark, add a configuration of a new model family (its
+    config, plain reference and operation count), a traffic mix, a
+    per-layer metric and a cell on four chips as new files, and list them
+    in the manifest: the harness finds and counts them, and no file that
+    was there changes."""
+    import counting
+
     root = tmp_path / "repo"
     chip = root / "benchmarks" / "chip"
     shutil.copytree(harness.HERE, chip,
@@ -75,23 +111,33 @@ def test_a_new_cell_is_files_and_entries(tmp_path):
     config = harness.load_json("configs", "paper-cnn")
     config["name"] = "paper-cnn-wide"
     (chip / "configs" / "paper-cnn-wide.json").write_text(json.dumps(config))
+    stub = dict(config, name="stub-net",
+                model={"family": "stubnet", "width": 64, "depth": 3})
+    (chip / "configs" / "stub-net.json").write_text(json.dumps(stub))
+    (chip / "reference" / "stubnet.py").write_text(STUB_REFERENCE)
+    (chip / "counts" / "stubnet.py").write_text(STUB_COUNTS)
     traffic = harness.load_json("traffic", "modest-diurnal-n100")
     traffic["sample_size"] = 20
     (chip / "traffic" / "modest-wide.json").write_text(json.dumps(traffic))
     (chip / "layers" / "rounds_seen.py").write_text(
         "def read(run):\n    return float(run.window.rounds)\n")
-    m["configs"].append(dict(m["configs"][0], name="paper-cnn-wide",
-                             file="benchmarks/chip/configs/paper-cnn-wide.json"))
-    m["workloads"].append({"name": "cnn-wide", "config": "paper-cnn-wide",
-                           "traffic": "modest-wide", "chips": 1,
-                           "why": "a test cell"})
+    for name in ("paper-cnn-wide", "stub-net"):
+        m["configs"].append(dict(m["configs"][0], name=name,
+                                 file=f"benchmarks/chip/configs/{name}.json"))
+    m["workloads"] += [
+        {"name": "cnn-wide", "config": "paper-cnn-wide",
+         "traffic": "modest-wide", "chips": 1, "why": "a test cell"},
+        {"name": "stub-four", "config": "stub-net",
+         "traffic": "modest-wide", "chips": 4,
+         "why": "a test cell on four chips"}]
     m["per_layer"].append({"name": "rounds_seen", "unit": "rounds",
                            "better": "higher", "source": "host_clock",
                            "layer": "session", "moves": "wall_s_per_round",
-                           "workloads": ["cnn-wide"]})
+                           "workloads": ["cnn-wide", "stub-four"]})
     (root / "BENCHMARK.json").write_text(json.dumps(m))
 
     got = harness.manifest(str(root))
+    assert four_chip_cells_allowed(got)
     w = harness.workload(got, "cnn-wide")
     assert harness.load_json("configs", w["config"], str(chip))["name"] \
         == "paper-cnn-wide"
@@ -105,6 +151,14 @@ def test_a_new_cell_is_files_and_entries(tmp_path):
     assert found["rounds_seen"](type("Run", (), {"window": Win})) == 7.0
     assert "rounds_seen" not in harness.readers(got, "cnn-modest-diurnal",
                                                 str(chip))
+    four = harness.workload(got, "stub-four")
+    assert four["chips"] == 4
+    model = harness.load_json("configs", four["config"], str(chip))["model"]
+    assert counting.train_flops_per_sample(model, str(chip)) == 6 * 64 * 3
+    ref = harness.load_module("reference", model["family"], str(chip))
+    assert ref.evaluate({}, None, None) == {"loss": 1.0}
+    assert set(harness.readers(got, "stub-four", str(chip))) == {
+        "rounds_seen"}
     assert all(p.read_bytes() == b for p, b in before.items())
 
 

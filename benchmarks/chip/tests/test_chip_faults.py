@@ -68,8 +68,26 @@ def _evaluation_altered(monkeypatch):
     monkeypatch.setattr(JaxTask, "evaluate_many", evaluate_many)
 
 
+def _one_slot_unchanged(monkeypatch):
+    """The last slot of every cohort group returns its job's parameters
+    unchanged, the other slots train: a fault that reaches only some of
+    the jobs (one vmap slot, or a whole group of one job)."""
+    real = cohort.BatchedEngine._run_group
+
+    def run_group(self, pairs):
+        real(self, pairs)
+        job = pairs[-1][0]
+        out, *rest = self._done[job.key]
+        self._done[job.key] = (
+            FlatModel(cohort.as_buffer(job.params, self.spec), out.spec),
+            *rest)
+
+    monkeypatch.setattr(cohort.BatchedEngine, "_run_group", run_group)
+
+
 FAULTS = {"state_unchanged": (_state_unchanged, "train_gap"),
           "half_batch": (_half_batch, "train_gap"),
+          "one_slot_unchanged": (_one_slot_unchanged, "train_gap"),
           "aggregate_altered": (_aggregate_altered, "agg_gap"),
           "evaluation_altered": (_evaluation_altered, "eval_loss_gap")}
 
@@ -83,3 +101,9 @@ def test_a_broken_timed_path_is_not_correct(monkeypatch, name, fault):
     win, _, checks = tiny.run_tiny(cell, seconds=0.5)
     assert not harness.correct(win, checks)
     assert checks[number]["value"] > checks[number]["limit"], checks
+
+
+def test_a_run_that_compares_nothing_is_not_correct():
+    win = harness.Window(sessions=[harness.SessionStats(round_walls=[1.0])])
+    assert not harness.correct(win, {})
+    assert harness.correct(win, {"train_gap": {"value": 0.0, "limit": 0.08}})

@@ -256,3 +256,24 @@ def test_trace_session_on_the_cpu():
     # the CPU's trace has no TPU plane: the reductions find nothing
     assert "numbers" not in first and "bench" not in first
     json.dumps(out)
+
+
+def test_the_seven_readers_on_the_recorded_trace():
+    """The benchmark's readers ``layers/<name>.py`` read from a window what
+    :func:`program_trace.numbers` reads from the same trace and counters."""
+    import harness
+    from run_cell import Run
+
+    doc, planes = _spans_trace()
+    t = pt.reduce_program(planes)
+    c = doc["counters"]
+    window = harness.Window(program=t, traced=harness.SessionStats(
+        rounds=c["rounds"], flushes=c["flushes"], jobs=c["jobs_run"],
+        jobs_served=c["jobs_served"], batch_bytes_h2d=c["batch_bytes_h2d"]))
+    run = Run(window, 0.0, {}, doc["n_params"])
+    want = pt.numbers(t, pt.Counters(**c))
+    got = {name: harness.load_module("layers", name).read(run)
+           for name in pt.READERS}
+    assert got == want and all(v is not None for v in got.values())
+    assert all(harness.load_module("layers", name).read(
+        Run(harness.Window(), 0.0, {}, 1)) is None for name in pt.READERS)

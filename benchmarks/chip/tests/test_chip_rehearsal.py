@@ -6,6 +6,7 @@ reference computed in bfloat16 in the program's place) does not."""
 
 import math
 
+import numpy as np
 import pytest
 
 import harness
@@ -90,3 +91,50 @@ def test_control_script_reads_every_stand_in(rehearsal):
     for kind in ("control", "half_batch"):
         assert got[kind + "_leaves"]["_whole"] > \
             got["train_leaves"]["_whole"]
+
+
+def test_samples_are_held_on_the_host(rehearsal):
+    """Every sampled answer the recorder holds after the window is on the
+    host, and the checks read from it what they read from the device's
+    copies of the same answers."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.engine.flat import FlatModel
+
+    cell, _, recorder, checks = rehearsal
+    assert not recorder.pending
+    kept = recorder.train.kept + recorder.agg.kept + recorder.evals.kept + \
+        [recorder.longest]
+    leaves = jax.tree.leaves([s.item for s in kept], is_leaf=lambda v:
+                             isinstance(v, harness.HostModel))
+    assert not any(isinstance(v, jax.Array) for v in leaves)
+    assert any(isinstance(v, harness.HostModel) for v in leaves)
+
+    def on_device(v):
+        if isinstance(v, harness.HostModel):
+            return FlatModel(jnp.asarray(v.buffer), v.spec)
+        return jnp.asarray(v) if isinstance(v, np.ndarray) else v
+
+    moved = {id(s): types.SimpleNamespace(item=jax.tree.map(
+        on_device, s.item, is_leaf=lambda v: isinstance(
+            v, harness.HostModel))) for s in kept}
+    device = harness.Recorder(cell.seed)
+    for name in ("train", "agg", "evals"):
+        getattr(device, name).kept = [moved[id(s)] for s in
+                                      getattr(recorder, name).kept]
+    device.longest = moved[id(recorder.longest)]
+    assert harness.check(cell, device) == checks
+
+
+def test_sample_counts_come_from_the_config(rehearsal):
+    cell = rehearsal[0]
+    assert harness.sample_counts(cell.config) == {"train": 8, "agg": 8,
+                                                  "eval": 4}
+    config = dict(cell.config, check={"train": 2, "eval": 1})
+    counts = harness.sample_counts(config)
+    assert counts == {"train": 2, "agg": 8, "eval": 1}
+    r = harness.Recorder(1, counts)
+    assert (r.train.k, r.agg.k, r.evals.k) == (2, 8, 1)

@@ -16,6 +16,9 @@ if CHIP not in sys.path:
 import harness  # noqa: E402
 
 TINY_NODES = 6
+# simulated seconds of a tiny session and of the warm-up session, by
+# session kind: a few rounds each (a tiny D-SGD round is half a second)
+TINY_SECONDS = {"dsgd": (5.0, 2.0)}
 
 
 def tiny(workload_name: str):
@@ -24,8 +27,9 @@ def tiny(workload_name: str):
     config = copy.deepcopy(harness.load_json("configs", w["config"]))
     traffic = copy.deepcopy(harness.load_json("traffic", w["traffic"]))
     config["dataset"].update(train=TINY_NODES * 45, test=128)
-    traffic.update(nodes=TINY_NODES, session_seconds=40.0,
-                   warmup_seconds=10.0, eval_every_rounds=2)
+    session_s, warmup_s = TINY_SECONDS.get(traffic["session"], (40.0, 10.0))
+    traffic.update(nodes=TINY_NODES, session_seconds=session_s,
+                   warmup_seconds=warmup_s, eval_every_rounds=2)
     traffic.update(sample_size=3, pass_sessions=2)
     traffic["warm"] = {"max_group": 3, "max_agg": 3, "max_eval": 2}
     return config, traffic
@@ -41,6 +45,7 @@ def run_tiny(cell, seconds: float = 1.0):
     chip does after finding the chip: (window, recorder, checks)."""
     compiles = harness.CompileCounter()
     harness.warm_shapes(cell)
-    recorder = harness.Recorder(cell.seed)
+    recorder = harness.Recorder(cell.seed,
+                                harness.sample_counts(cell.config))
     win = harness.run_window(cell, seconds, recorder, compiles)
     return win, recorder, harness.check(cell, recorder)

@@ -169,9 +169,3 @@ def reduce_planes(planes, n_gaps: int = 10) -> Optional[Reduction]:
                      gaps=[(_label(spans, (s + e) / 2), e - s)
                            for s, e in gaps])
 
-
-def reduce_file(path: str, n_gaps: int = 10) -> Optional[Reduction]:
-    """Reduce a ``.xplane.pb`` file."""
-    from jax.profiler import ProfileData
-
-    return reduce_planes(ProfileData.from_file(path).planes, n_gaps)

@@ -45,55 +45,6 @@ def log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
 
 
-class EngineRecorder(harness.Recorder):
-    """The harness's recorder that also keeps the session's engine and the
-    argument shapes of each cohort program it calls (to compile their text
-    for the op scopes)."""
-
-    def __init__(self, seed: int):
-        super().__init__(seed)
-        self.engine = None
-        self.calls = {}                   # (program, shapes) -> shape args
-
-    def attach(self, engine) -> None:
-        import jax
-
-        super().attach(engine)
-        self.engine = engine
-        for attr in ("_step", "_scan"):
-            fn = getattr(engine, attr, None)
-            if fn is None:
-                continue
-
-            def watch(*args, _fn=fn):
-                key = (_fn, args[0].shape, args[2].shape)
-                if key not in self.calls:
-                    self.calls[key] = jax.tree.map(
-                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                        args)
-                return _fn(*args)
-
-            setattr(engine, attr, watch)
-
-    def program_texts(self):
-        """The compiled text of every cohort program shape called."""
-        out = []
-        for (fn, _, _), args in self.calls.items():
-            try:
-                out.append(fn.lower(*args).compile().as_text())
-            except Exception as e:            # the text is optional
-                log(f"no compiled text: {e!r}")
-        return out
-
-    def counters(self, rounds: int) -> program_trace.Counters:
-        e = self.engine
-        return program_trace.Counters(
-            flushes=getattr(e, "flushes", 0),
-            jobs_run=getattr(e, "jobs_run", 0), rounds=rounds,
-            jobs_served=getattr(e, "jobs_served", None),
-            batch_bytes_h2d=getattr(e, "batch_bytes_h2d", None))
-
-
 def span_cost_us(n: int = 200_000) -> dict:
     """Host microseconds per span with the profiler off, without and with
     one metadata keyword, and of the bare loop."""
@@ -125,8 +76,8 @@ def traced_session(cell, index: int, seed: int, keep: str = None):
     options.host_tracer_level = 1
     options.python_tracer_level = 0
     options.enable_hlo_proto = False
-    recorder = EngineRecorder(seed)
-    recorder.sync = True
+    recorder = harness.Recorder(seed)
+    recorder.sync = recorder.note_programs = True
     tmp = tempfile.mkdtemp(prefix="trace_session_")
     try:
         t0 = time.perf_counter()
@@ -156,11 +107,11 @@ def reading(stats, recorder, data, seconds) -> dict:
     t = time.perf_counter()
     # ``data.planes`` can be iterated once: each reduction asks anew
     base = trace_reduce.reduce_planes(data.planes)
-    texts = recorder.program_texts()
+    texts = recorder.program_texts(log)
     scopes = program_trace.hlo_op_scopes(texts)
     prog = program_trace.reduce_program(data.planes, scopes)
     seconds["reduce"] = time.perf_counter() - t
-    c = recorder.counters(stats.rounds)
+    c = program_trace.session_counters(stats)
     out = {"seconds": seconds, "counters": vars(c),
            "session": {"rounds": stats.rounds, "events": stats.events,
                        "wall_s": stats.wall_s},
@@ -226,7 +177,8 @@ def main() -> int:
     log(f"compile cache: {enable_compile_cache()}")
     config = harness.load_json("configs", w["config"])
     traffic = harness.load_json("traffic", w["traffic"])
-    cell = harness.build_cell(w["name"], config, traffic, args.seed)
+    cell = harness.build_cell(w["name"], config, traffic, args.seed,
+                              devices=devices)
     harness.warm_shapes(cell, log=log)
     harness.run_session(cell, -1, harness.Recorder(args.seed),
                         traffic["warmup_seconds"])
