@@ -57,8 +57,8 @@ def per_leaf(cell, recorder, against=None) -> dict:
         train["lr"] = train["lr"] * lr_scale
         kw = dict(batch_size=bs, epochs=epochs, seed=seed, train_cfg=train,
                   model_cfg=harness.model_cfg(cell))
-        want = harness.reference_on_host(cell.reference.train, start,
-                                         client.x, client.y, **kw)
+        want = harness.reference_on(cell, cell.reference.train, start,
+                                    client.x, client.y, **kw)
         got = harness.leaves(result) if against is None else \
             against(start, client.x, client.y, **kw)
         gaps = harness.change_gaps(got, want, start)
@@ -106,8 +106,8 @@ def pad_witness(cell, recorder, extra: int = 16) -> list:
         p = harness.leaves(model)
 
         def ref(lo, hi):
-            return harness.reference_on_host(
-                cell.reference.evaluate, p, x[lo:hi], y[lo:hi],
+            return harness.reference_on(
+                cell, cell.reference.evaluate, p, x[lo:hi], y[lo:hi],
                 model_cfg=cfg)["loss"]
 
         plain = ref(0, n)

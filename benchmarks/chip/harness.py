@@ -21,6 +21,10 @@ metric lives in a file of its own, found by the name in
 * ``counts/<family>.py`` — the operation count of a model family;
 * ``layers/<metric>.py`` — one reader per per-layer metric.
 
+A config names its task factory and the factory's keyword arguments
+(``task``), the engine counters its readers read (``counters``), and
+where the check's reference runs (``reference_device``).
+
 A cell's ``chips`` decide its engine (:func:`engine_factory`): one chip
 runs the batched engine the sessions pick themselves, four run every
 session, and the warm-up, on a ``MeshEngine`` over the cell's devices.
@@ -49,6 +53,8 @@ for _p in (os.path.join(REPO, "src"), HERE):
 # sampled answers of each kind checked per run, unless the config's
 # ``check`` key sets them: training jobs, aggregations, evaluated models
 SAMPLES = {"train": 8, "agg": 8, "eval": 4}
+# where the check's reference may run (a config's ``reference_device``)
+REFERENCE_DEVICES = ("cpu", "chip")
 
 
 # ----------------------------------------------------------------- discovery
@@ -137,6 +143,7 @@ class Cell:
     shards: List[tuple] = field(default_factory=list)
     test: tuple = ()
     new_engine: Callable = None           # a fresh engine for a session
+    devices: Optional[list] = None        # the cell's chips (None: one)
 
 
 def train_config(config: dict, seed: int = 0):
@@ -146,10 +153,14 @@ def train_config(config: dict, seed: int = 0):
 
 
 def build_task(config: dict):
+    """The config's task: ``task.factory`` of ``repro.models.tasks`` with
+    the train config as ``tcfg=`` and ``task.overrides`` as further
+    keywords (for ``lm_task``: ``arch``, ``reduce`` and the model's
+    overrides)."""
     import repro.models.tasks as tasks
 
     factory = getattr(tasks, config["task"]["factory"])
-    return factory(train_config(config), **config["task"]["overrides"])
+    return factory(tcfg=train_config(config), **config["task"]["overrides"])
 
 
 def build_profile(traffic: dict, seed: int, base: str = HERE):
@@ -210,7 +221,18 @@ def build_cell(name: str, config: dict, traffic: dict, seed: int,
     cell's chips (:func:`engine_factory`)."""
     from repro.data.loader import ClientDataset, FederatedData
 
-    cell = Cell(name, config, traffic, seed)
+    where = config.get("reference_device", "cpu")
+    if where not in REFERENCE_DEVICES:
+        raise ValueError(f"reference_device {where!r} is not one of "
+                         f"{REFERENCE_DEVICES}")
+    if where == "chip":
+        import jax
+
+        first = (devices or jax.devices())[0]
+        if first.platform == "cpu":
+            raise ValueError("reference_device 'chip' needs an accelerator; "
+                             "the cell's first device is a CPU")
+    cell = Cell(name, config, traffic, seed, devices=devices)
     ds = config["dataset"]
     gen = load_module("datasets", ds["kind"], base).make(
         ds, traffic["nodes"], seed)
@@ -571,6 +593,9 @@ class SessionStats:
     agg_sizes: List[int] = field(default_factory=list)
     evals: int = 0                        # models evaluated
     host_s: Dict[str, float] = field(default_factory=dict)
+    # the engine counters the config lists, by name (None where the
+    # engine has no such counter)
+    counters: Dict[str, Optional[float]] = field(default_factory=dict)
     ok: bool = True
 
 
@@ -610,6 +635,8 @@ def run_session(cell: Cell, index: int, recorder: Recorder,
     stats.jobs_served = getattr(e, "jobs_served", None)
     stats.batch_bytes_h2d = getattr(e, "batch_bytes_h2d", None)
     stats.shard_uploads = getattr(e, "shard_uploads", None)
+    stats.counters = {name: getattr(e, name, None)
+                      for name in cell.config.get("counters", ())}
     stats.agg_sizes = recorder.agg_sizes[n_agg:]
     stats.ok = finite_history(result)
     if len(stats.round_walls) != len(result.round_times):
@@ -764,14 +791,18 @@ def find_xplane(root: str) -> Optional[str]:
 
 
 def leaves(params) -> Dict[str, np.ndarray]:
-    """A model the program produced, as named float32 host arrays."""
+    """A model the program produced, as float32 host arrays named by
+    their path in the parameter tree (``"conv1"``, ``"layers/attn/wq"``)."""
     import jax
 
     from repro.engine.flat import as_tree
 
     tree = params.tree() if isinstance(params, HostModel) else \
         jax.device_get(as_tree(params))
-    return {k: np.asarray(v, np.float32) for k, v in tree.items()}
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                     for k in path): np.asarray(v, np.float32)
+            for path, v in flat}
 
 
 def leaf_gaps(got: dict, want: dict, scale: dict) -> Dict[str, float]:
@@ -837,8 +868,8 @@ def train_gaps(cell: Cell, jobs, against=None, exact: bool = False
         train["lr"] = train["lr"] * lr_scale
         kw = dict(batch_size=bs, epochs=epochs, seed=seed, train_cfg=train,
                   model_cfg=model_cfg(cell, exact))
-        want = reference_on_host(cell.reference.train, start, client.x,
-                                 client.y, **kw)
+        want = reference_on(cell, cell.reference.train, start, client.x,
+                            client.y, **kw)
         got = (leaves(out) if against is None else
                against(start, client.x, client.y, **kw))
         gaps.append(float(np.median(list(
@@ -871,8 +902,8 @@ def eval_gaps(cell: Cell, evals, against=None) -> Dict[str, List[float]]:
     x, y = cell.test
     for model, metrics in evals:
         p = leaves(model)
-        want = reference_on_host(cell.reference.evaluate, p, x, y,
-                                 model_cfg=model_cfg(cell))
+        want = reference_on(cell, cell.reference.evaluate, p, x, y,
+                            model_cfg=model_cfg(cell))
         got = metrics if against is None else against(
             p, x, y, model_cfg=model_cfg(cell))
         out["eval_loss_gap"].append(
@@ -883,11 +914,26 @@ def eval_gaps(cell: Cell, evals, against=None) -> Dict[str, List[float]]:
     return out
 
 
-def reference_on_host(fn, *args, **kw):
-    """Run the float32 reference on the host's CPU: exact float32, free of
-    the accelerator's matmul passes, and off the chip's memory."""
+def reference_on(cell: Cell, fn, *args, **kw):
+    """Run the float32 reference where the config's ``reference_device``
+    says: ``"cpu"`` (the default), the host's CPU, exact float32 and off
+    the chip's memory; ``"chip"``, the cell's first device (an
+    accelerator: :func:`build_cell` refuses a CPU), under the ``highest``
+    matmul precision, so that float32 stays all but exact there too (the
+    operand rounding that ``matmul_operands`` states is the reference's
+    own and is kept). Either runs after the window's clock has stopped.
+
+    The two places do not read alike: on a TPU the chip's reference reads
+    the program's gaps lower than the host's. A config's limits, and the
+    control's and the faults' readings they are set between, hold only for
+    the place they were read at: a cell reads them with ``control.py`` at
+    the ``reference_device`` it sets."""
     import jax
 
+    if cell.config.get("reference_device", "cpu") == "chip":
+        with jax.default_device((cell.devices or jax.devices())[0]), \
+                jax.default_matmul_precision("highest"):
+            return fn(*args, **kw)
     with jax.default_device(jax.devices("cpu")[0]):
         return fn(*args, **kw)
 
@@ -910,8 +956,8 @@ def stand_in(cell: Cell, kind: str) -> Dict[str, Callable]:
     ref = cell.reference
     if kind == "half_batch":
         def half(*a, **kw):
-            return reference_on_host(ref.train, *a,
-                                     rows=kw["batch_size"] // 2, **kw)
+            return reference_on(cell, ref.train, *a,
+                                rows=kw["batch_size"] // 2, **kw)
 
         return {"train": half}
     if kind != "control":

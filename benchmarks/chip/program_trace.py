@@ -7,8 +7,10 @@ reads, over the same ``bench.window``, the program's ``repro.*`` host spans
 assembly, dispatch, aggregation and evaluation), labels the device's
 longest idle gaps by the innermost span of either prefix, and splits the
 device time of the cohort train step's programs (``jit_step``,
-``jit_train_scan``) by the ``jax.named_scope`` each op was traced under
-(``unpack``, ``grad``, ``pack``, ``optimizer``).
+``jit_train_scan``) twice: by the step scope each op was traced under
+(``unpack``, ``grad``, ``pack``, ``optimizer``), and by the model's own
+``jax.named_scope`` s below it (:func:`model_scope_of`), whose names are
+read from the paths and listed nowhere here.
 
 A TPU trace's op events carry no ``op_name``: an op's scope comes from the
 ``op_name`` metadata of the compiled program's text (:func:`hlo_op_scopes`),
@@ -41,12 +43,72 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _OPERAND = re.compile(r"%[\w.\-]+")
 
 
+# what JAX itself writes on an ``op_name`` path around a model's scopes:
+# the labels of control flow and rematerialisation, and the names of the
+# primitives that nest others
+JAX_LABELS = frozenset((
+    "while", "body", "cond", "body_pred", "checkpoint",
+    "rematted_computation", "closed_call", "core_call", "remat", "remat2",
+    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_lin", "pjit", "scan", "shard_map", "run_state", "pallas_call"))
+_BRANCH = re.compile(r"^branch_\d+_fun$")
+_WRAPPED = re.compile(r"^([\w.\-]+)\((.*)\)$")
+
+
+@dataclass(frozen=True)
+class OpScope:
+    """Where an op of a step program was traced: its step scope (one of
+    :data:`SCOPES`, or ``""``) and the path of the model's own scopes
+    below it (``"/"``-joined, outermost first; ``""`` where none)."""
+    step: str = ""
+    model: str = ""
+
+
 def scope_of(op_name: str) -> str:
     """The first step scope on an ``op_name`` path, else ``""``."""
     for part in op_name.split("/"):
         if part in SCOPES:
             return part
     return ""
+
+
+def _scope_name(part: str) -> str:
+    """The ``jax.named_scope`` name a path component stands for, else
+    ``""``. A transform wraps the scope it entered (``vmap(jvp(attn))``,
+    ``transpose(jvp())``): the innermost name counts. A nested jit's
+    function (``jit(relu)``), a step scope and a label of JAX's own are
+    no model scope."""
+    while True:
+        m = _WRAPPED.match(part)
+        if m is None or m.group(1) in ("jit", "pmap"):
+            break
+        part = m.group(2)
+    if not part or _WRAPPED.match(part) or part in SCOPES or \
+            part in JAX_LABELS or _BRANCH.match(part):
+        return ""
+    return part
+
+
+def model_scope_of(op_name: str) -> str:
+    """The path of the model's own scopes on an ``op_name`` path below its
+    first step scope, outermost first, without the op's primitive; ``""``
+    where there is no step scope or no model scope under it. The TPU
+    compiler gives an op it made of several ops their names joined by
+    ``;`` (``.../pack/reshape;jit(step)/grad/.../reshape``): the op takes
+    the step scope of the first name that has one, as :func:`scope_of`
+    reads it, and the model scopes of that same name."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if part in SCOPES:
+            rest = parts[i + 1:]
+            end = next((j for j, p in enumerate(rest) if ";" in p),
+                       len(rest) - 1)
+            return "/".join(n for n in map(_scope_name, rest[:end]) if n)
+    return ""
+
+
+def op_scope(op_name: str) -> OpScope:
+    return OpScope(scope_of(op_name), model_scope_of(op_name))
 
 
 def op_key(text: str) -> str:
@@ -68,14 +130,19 @@ def op_key(text: str) -> str:
     return f"{head} = {rest.split(' ', 1)[0]}"
 
 
-def _program_scopes(text: str) -> Dict[str, str]:
-    """Op key -> step scope of one compiled program. An op the compiler
-    made (a layout copy, the halves of an async copy, the writes it turns
-    a concatenation into) carries no ``op_name``. It takes the scope of
-    the ops it feeds, the earliest in the step's order; where the data it
-    moves comes from a scope two steps before that, it takes the scope
-    between them (writes from ``grad`` into a buffer ``optimizer`` reads
-    are ``pack``); an op that feeds nothing takes its operands' scope."""
+def _program_scopes(text: str) -> Dict[str, OpScope]:
+    """Op key -> :class:`OpScope` of one compiled program. An op the
+    compiler made (a layout copy, the halves of an async copy, the writes
+    it turns a concatenation into) carries no ``op_name``. It takes the
+    scope of the ops it feeds, the earliest in the step's order; where the
+    data it moves comes from a scope two steps before that, it takes the
+    scope between them (writes from ``grad`` into a buffer ``optimizer``
+    reads are ``pack``), with no model scope; an op that feeds nothing
+    takes its operands' scope. An op that the compiler merged from ops of
+    several scopes (a fusion) carries the ``op_name`` of one of them, its
+    root, and takes that op's step and model scope. The model scope
+    always comes from the op the step scope came from, so the step scopes
+    read as they would alone."""
     keys, own, deps = {}, {}, {}
     for line in text.splitlines():
         if " = " not in line or not line.startswith("  "):
@@ -84,50 +151,61 @@ def _program_scopes(text: str) -> Dict[str, str]:
         name = key.split(" = ")[0]
         m = _OP_NAME.search(line)
         keys[name] = key
-        own[name] = scope_of(m.group(1)) if m else ""
+        own[name] = op_scope(m.group(1)) if m else OpScope()
         deps[name] = _OPERAND.findall(line.split(" = ", 1)[1])
     order = {s: i for i, s in enumerate(SCOPES)}
+
+    def rank(o: OpScope) -> int:
+        return order[o.step]
+
     before, users = {}, {}
     for name in own:                       # the text lists operands first
-        before[name] = own[name] or max(
-            (before[d] for d in deps[name] if before.get(d)),
-            key=order.get, default="")
+        before[name] = own[name] if own[name].step else max(
+            (before[d] for d in deps[name]
+             if d in before and before[d].step),
+            key=rank, default=OpScope())
         for d in deps[name]:
             users.setdefault(d, []).append(name)
-    scope: Dict[str, str] = {}
+    scope: Dict[str, OpScope] = {}
     for name in reversed(list(own)):
-        after = min((scope[u] for u in users.get(name, ()) if scope.get(u)),
-                    key=order.get, default="")
+        after = min((scope[u] for u in users.get(name, ())
+                     if u in scope and scope[u].step),
+                    key=rank, default=OpScope())
         came = before[name]
-        if own[name] or not after:
-            scope[name] = own[name] or came
-        elif came and order[after] - order[came] > 1:
-            scope[name] = SCOPES[order[came] + 1]
+        if own[name].step or not after.step:
+            scope[name] = own[name] if own[name].step else came
+        elif came.step and rank(after) - rank(came) > 1:
+            scope[name] = OpScope(SCOPES[rank(came) + 1])
         else:
             scope[name] = after
     return {keys[n]: s for n, s in scope.items()}
 
 
-def hlo_op_scopes(texts: Iterable[str]) -> Dict[str, str]:
-    """Op key (:func:`op_key`) and bare op name -> step scope, from the
-    ``as_text()`` of compiled programs. A bare name that different
-    programs give different scopes is left out."""
-    keyed: Dict[str, str] = {}
+def hlo_op_scopes(texts: Iterable[str]) -> Dict[str, OpScope]:
+    """Op key (:func:`op_key`) and bare op name -> :class:`OpScope`, from
+    the ``as_text()`` of compiled programs. A bare name that different
+    programs give different step scopes is left out; one they give the
+    same step scope but different model scopes keeps the step scope
+    alone."""
+    keyed: Dict[str, OpScope] = {}
     bare: Dict[str, set] = {}
     for text in texts:
         for key, scope in _program_scopes(text).items():
             keyed[key] = scope
             bare.setdefault(key.split(" = ")[0], set()).add(scope)
-    keyed.update({k: next(iter(v)) for k, v in bare.items() if len(v) == 1})
+    for name, seen in bare.items():
+        if len({o.step for o in seen}) == 1:
+            keyed[name] = next(iter(seen)) if len(seen) == 1 else \
+                OpScope(next(iter(seen)).step)
     return keyed
 
 
-def _event_scope(ev, scopes: Dict[str, str]) -> str:
+def _event_scope(ev, scopes: Dict[str, OpScope]) -> OpScope:
     tf_op = _stats(ev).get("tf_op")
     if tf_op:
-        return scope_of(str(tf_op))
+        return op_scope(str(tf_op))
     key = op_key(ev.name)
-    return scopes.get(key, scopes.get(key.split(" = ")[0], ""))
+    return scopes.get(key, scopes.get(key.split(" = ")[0], OpScope()))
 
 
 def _merge(intervals) -> List[Tuple[float, float]]:
@@ -161,11 +239,31 @@ class ProgramTrace:
     spans: List[Tuple[str, float, float]] = field(default_factory=list)
     idle: List[List[Tuple[float, float]]] = field(default_factory=list)
     scope_ns: Dict[str, float] = field(default_factory=dict)
+    # the step programs' device ns by the path of the model's scopes
+    # (:attr:`OpScope.model`; ``""``: under none)
+    model_scope_ns: Dict[str, float] = field(default_factory=dict)
     gaps: List[Tuple[str, float]] = field(default_factory=list)
 
     @property
     def window_ns(self) -> float:
         return self.window[1] - self.window[0]
+
+    @property
+    def step_ns(self) -> float:
+        """Device ns of the step programs' ops in the window."""
+        return sum(self.scope_ns.values())
+
+    def under_ns(self, scope: str) -> float:
+        """Device ns of the step programs' ops under the model scope
+        ``scope``, at any depth of their path."""
+        return sum(ns for path, ns in self.model_scope_ns.items()
+                   if scope in path.split("/"))
+
+    def under_share(self, scope: str) -> Optional[float]:
+        """Percent of the step programs' device time under the model scope
+        ``scope``; None where no op is under it."""
+        ns = self.under_ns(scope)
+        return 100.0 * ns / self.step_ns if ns else None
 
     def union(self, *names: str) -> List[Tuple[float, float]]:
         """The named spans, clipped to the window and merged."""
@@ -196,7 +294,8 @@ class ProgramTrace:
         return sum(_overlap_ns(d, u) for d in self.idle) / self.devices
 
 
-def reduce_program(planes, op_scopes: Optional[Dict[str, str]] = None,
+def reduce_program(planes,
+                   op_scopes: Optional[Dict[str, OpScope]] = None,
                    n_gaps: int = 10) -> Optional[ProgramTrace]:
     """``planes`` as ``jax.profiler.ProfileData`` gives them; ``op_scopes``
     from :func:`hlo_op_scopes` for traces whose op events carry no
@@ -219,7 +318,8 @@ def reduce_program(planes, op_scopes: Optional[Dict[str, str]] = None,
     if not windows or not devices:
         return None
     lo, hi = windows[0]
-    idle, scope_ns, known = [], {}, {}     # known: op text -> scope
+    idle, scope_ns, model_ns = [], {}, {}
+    known: Dict[str, OpScope] = {}         # op text -> scope
     for lines in devices:
         modules = sorted(
             (ev.start_ns, ev.start_ns + ev.duration_ns,
@@ -240,7 +340,8 @@ def reduce_program(planes, op_scopes: Optional[Dict[str, str]] = None,
                 scope = known.get(ev.name)
                 if scope is None:
                     scope = known[ev.name] = _event_scope(ev, op_scopes)
-                scope_ns[scope] = scope_ns.get(scope, 0.0) + e - s
+                scope_ns[scope.step] = scope_ns.get(scope.step, 0.0) + e - s
+                model_ns[scope.model] = model_ns.get(scope.model, 0.0) + e - s
         t, gaps = lo, []
         for s, e in _merge(busy) + [(hi, hi)]:
             if s > t:
@@ -250,7 +351,7 @@ def reduce_program(planes, op_scopes: Optional[Dict[str, str]] = None,
     longest = sorted(idle[0], key=lambda g: g[0] - g[1])[:n_gaps]
     return ProgramTrace(
         window=(lo, hi), devices=len(devices), spans=spans, idle=idle,
-        scope_ns=scope_ns,
+        scope_ns=scope_ns, model_scope_ns=model_ns,
         # labelled by the innermost span of either prefix
         gaps=[(_label(spans, (s + e) / 2), e - s) for s, e in longest])
 
@@ -304,7 +405,7 @@ def loop_self_share(t: ProgramTrace, c: Counters):
 def train_glue_share(t: ProgramTrace, c: Counters):
     """Share of the cohort step's device time under ``unpack`` or
     ``pack``."""
-    total = sum(t.scope_ns.values())
+    total = t.step_ns
     if not total or not any(t.scope_ns.get(s) for s in SCOPES):
         return None
     return 100.0 * sum(t.scope_ns.get(s, 0.0) for s in GLUE) / total
@@ -312,7 +413,7 @@ def train_glue_share(t: ProgramTrace, c: Counters):
 
 def scope_coverage(t: ProgramTrace) -> Optional[float]:
     """Share of the cohort step's device time that some scope names."""
-    total = sum(t.scope_ns.values())
+    total = t.step_ns
     if not total:
         return None
     return 100.0 * sum(t.scope_ns.get(s, 0.0) for s in SCOPES) / total

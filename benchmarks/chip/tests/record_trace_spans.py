@@ -48,7 +48,7 @@ def slim(planes, scopes) -> list:
                 events = [[ev.name[:160], ev.start_ns, ev.duration_ns,
                            str(trace_reduce._stats(ev).get("hlo_module",
                                                            "")),
-                           program_trace._event_scope(ev, scopes)
+                           program_trace._event_scope(ev, scopes).step
                            if ops else ""]
                           for ev in line.events]
             else:

@@ -167,3 +167,47 @@ def test_an_unknown_name_is_an_error():
         harness.workload(M, "no-such-cell")
     with pytest.raises(KeyError):
         harness.load_json("configs", "no-such-config")
+
+
+def test_build_task_builds_the_cnn_task_as_before():
+    from repro.models.tasks import cnn_task
+
+    config = harness.load_json("configs", "paper-cnn")
+    got = harness.build_task(config)
+    want = cnn_task(harness.train_config(config))
+    assert got.cfg == want.cfg and got.tcfg == want.tcfg
+    assert got.flat_spec.n == config["params"]
+
+
+def test_build_task_builds_an_lm_task_from_its_keywords():
+    config = {"task": {"factory": "lm_task",
+                       "overrides": {"arch": "qwen3-moe-30b-a3b",
+                                     "reduce": True, "n_layers": 1,
+                                     "vocab": 128}},
+              "train": {"optimizer": "sgd", "lr": 0.05, "batch_size": 4}}
+    task = harness.build_task(config)
+    assert task.cfg.family == "moe" and task.cfg.name == "qwen3-moe-30b-a3b"
+    assert task.cfg.n_layers == 1 and task.cfg.vocab == 128
+    assert task.cfg.moe_num_experts == 4           # the reduced preset
+    assert task.tcfg == harness.train_config(config)
+
+
+def test_an_unknown_reference_device_is_refused():
+    import tiny
+
+    config, traffic = tiny.tiny("cnn-modest-diurnal")
+    with pytest.raises(ValueError, match="reference_device"):
+        harness.build_cell("x", dict(config, reference_device="tpu"),
+                           traffic, 1)
+
+
+def test_the_chip_reference_is_refused_without_an_accelerator():
+    """``reference_device: "chip"`` on a cell whose first device is a CPU
+    would run the reference on the host under another name, at limits read
+    on a chip: it is refused at build."""
+    import tiny
+
+    config, traffic = tiny.tiny("cnn-modest-diurnal")
+    with pytest.raises(ValueError, match="accelerator"):
+        harness.build_cell("x", dict(config, reference_device="chip"),
+                           traffic, 1)

@@ -76,3 +76,38 @@ def test_a_pool_session_does_the_same_work_for_every_seed():
     assert sa.rounds > 0
     assert (sa.rounds, sa.jobs, sa.flushes, sa.agg_sizes, sa.events) == \
         (sb.rounds, sb.jobs, sb.flushes, sb.agg_sizes, sb.events)
+
+
+TOKENS = {"kind": "tokens", "seq_len": 12, "vocab": 96, "per_node": 40,
+          "test": 32, "topics": 4, "skew": 0.9}
+
+
+def test_tokens_are_the_seeds():
+    make = harness.load_module("datasets", "tokens").make
+    a, b = make(TOKENS, 6, 2**31 + 5), make(TOKENS, 6, 2**31 + 5)
+    c = make(TOKENS, 6, 2**31 + 6)
+    for (x, y), (u, v) in zip(a["clients"] + [a["test"]],
+                              b["clients"] + [b["test"]]):
+        np.testing.assert_array_equal(x, u)
+        np.testing.assert_array_equal(y, v)
+    assert not np.array_equal(a["clients"][0][0], c["clients"][0][0])
+    x, y = a["clients"][0]
+    assert x.shape == y.shape == (40, 12) and x.dtype == np.int32
+    assert a["test"][0].shape == (32, 12)
+    np.testing.assert_array_equal(x[:, 1:], y[:, :-1])   # next tokens
+    assert 0 <= x.min() and max(x.max(), y.max()) < TOKENS["vocab"]
+
+
+def test_the_topic_skew_is_visible():
+    """At skew 0.9 nine tenths of a node's tokens (and a little more: the
+    rest fall in its band a quarter of the time) lie in its topic's band;
+    at skew 0 a quarter do, as in any band."""
+    make = harness.load_module("datasets", "tokens").make
+    width = TOKENS["vocab"] // TOKENS["topics"]
+    for skew, want in ((0.9, 0.9 + 0.1 / 4), (0.0, 1 / 4)):
+        d = make(dict(TOKENS, skew=skew, per_node=200), 8, 11)
+        for (x, _), topic in zip(d["clients"], d["node_topics"]):
+            share = np.mean(x // width == topic)
+            assert abs(share - want) < 0.05, (skew, topic, share)
+    d = make(TOKENS, 8, 11)
+    assert len(set(d["node_topics"].tolist())) > 1

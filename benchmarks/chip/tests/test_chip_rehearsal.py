@@ -138,3 +138,36 @@ def test_sample_counts_come_from_the_config(rehearsal):
     assert counts == {"train": 2, "agg": 8, "eval": 1}
     r = harness.Recorder(1, counts)
     assert (r.train.k, r.agg.k, r.evals.k) == (2, 8, 1)
+
+
+def test_the_two_reference_paths_agree_where_both_are_the_cpu(rehearsal):
+    """``reference_on``'s chip path (the cell's first device, ``highest``
+    matmul precision), driven on a cell whose first device is the host's
+    CPU, reads every number compared as the host path does. What it shows
+    is the path's plumbing: where both places are the CPU they agree. On a
+    TPU the chip's reference reads differently, and ``build_cell`` refuses
+    the chip path without an accelerator."""
+    import dataclasses
+
+    cell, _, recorder, checks = rehearsal
+    assert cell.config.get("reference_device", "cpu") == "cpu"
+    on_chip = dataclasses.replace(
+        cell, config=dict(cell.config, reference_device="chip"))
+    assert harness.check(on_chip, recorder) == checks
+
+
+def test_engine_counters_are_copied_by_name(rehearsal):
+    """The counters a config lists are copied from each session's engine
+    by name, ``None`` where the engine has none; a config that lists none
+    gets none."""
+    import dataclasses
+
+    cell, win, _, _ = rehearsal
+    assert all(s.counters == {} for s in win.sessions)
+    listed = dataclasses.replace(cell, config=dict(
+        cell.config, counters=["flushes", "shard_uploads", "no_such"]))
+    stats = harness.run_session(listed, 0, harness.Recorder(cell.seed), 5.0)
+    assert stats.counters == {"flushes": stats.flushes,
+                              "shard_uploads": stats.shard_uploads,
+                              "no_such": None}
+    assert stats.flushes > 0

@@ -21,11 +21,14 @@ TINY_NODES = 6
 TINY_SECONDS = {"dsgd": (5.0, 2.0)}
 
 
-def tiny(workload_name: str):
-    """(config, traffic) of a cell, cut to a CPU-test size."""
-    w = harness.workload(harness.manifest(), workload_name)
-    config = copy.deepcopy(harness.load_json("configs", w["config"]))
-    traffic = copy.deepcopy(harness.load_json("traffic", w["traffic"]))
+def tiny(workload_name: str, root: str = harness.REPO):
+    """(config, traffic) of a cell of the benchmark at ``root``, cut to a
+    CPU-test size."""
+    base = os.path.join(root, "benchmarks", "chip")
+    w = harness.workload(harness.manifest(root), workload_name)
+    config = copy.deepcopy(harness.load_json("configs", w["config"], base))
+    traffic = copy.deepcopy(harness.load_json("traffic", w["traffic"],
+                                              base))
     config["dataset"].update(train=TINY_NODES * 45, test=128)
     session_s, warmup_s = TINY_SECONDS.get(traffic["session"], (40.0, 10.0))
     traffic.update(nodes=TINY_NODES, session_seconds=session_s,
